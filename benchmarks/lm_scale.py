@@ -57,7 +57,7 @@ def hybrid_plane_report(mesh: str = "pod",
     `bytes accessed` is a no-fusion upper bound that would mask every
     collective-bound cell (EXPERIMENTS.md §Roofline); "xla" keeps the raw
     metric for comparison."""
-    from repro.launch.roofline import HBM_BW
+    from repro.launch.roofline import V5E
     rows = []
     for c in load_cells(dryrun_dir):
         if c.get("mesh") != mesh or c.get("status") != "ok":
@@ -67,7 +67,7 @@ def hybrid_plane_report(mesh: str = "pod",
             continue
         if memory == "floor":
             args = c.get("memory", {}).get("argument_size_in_bytes", 0)
-            t_mem = args / HBM_BW
+            t_mem = args / V5E.hbm_bw
         else:
             t_mem = r["t_memory"]
         swept, (thr, p) = sweep_cell(r["coll_per_op"], r["t_compute"],

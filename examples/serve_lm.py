@@ -1,79 +1,21 @@
-"""Serving example: batched requests through prefill-free decode with a
-tiny continuous-batching scheduler (slots are refilled as sequences
-finish).
+"""Serving example: batched requests through prefill-free decode with the
+serving launcher's continuous batching (slots are refilled as sequences
+finish; each slot decodes at its own position).
 
     PYTHONPATH=src python examples/serve_lm.py
 """
 
-import time
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.configs import ARCHS, reduced
-from repro.models import build_model
-from repro.runtime.serve import ServeConfig, make_serve_fns
+from repro.launch import serve
 
 
 def main():
-    cfg = reduced(ARCHS["smollm-360m"])
-    model = build_model(cfg, remat=False)
-    params = model.init(jax.random.PRNGKey(0))
-    scfg = ServeConfig(max_len=64)
-    _, decode_step, init_cache = make_serve_fns(cfg, scfg)
-    dec = jax.jit(decode_step)
-
-    SLOTS, MAX_NEW = 4, 12
-    rng = np.random.default_rng(0)
-    # request queue: (prompt tokens,)
-    queue = [rng.integers(1, cfg.vocab_size, size=rng.integers(2, 6))
-             for _ in range(10)]
-    cache = init_cache(SLOTS, scfg.max_len)
-    active = [None] * SLOTS          # (request_id, prompt, emitted)
-    results = {}
-    tok = jnp.zeros((SLOTS, 1), jnp.int32)
-    pos = 0
-    served = 0
-    t0 = time.time()
-
-    while (queue or any(active)) and pos < scfg.max_len - 1:
-        for s in range(SLOTS):
-            if active[s] is None and queue:
-                rid = served
-                served += 1
-                active[s] = [rid, list(queue.pop(0)), []]
-        # feed next token per slot (prompt token or generated)
-        feed = np.zeros((SLOTS, 1), np.int32)
-        for s, a in enumerate(active):
-            if a is None:
-                continue
-            rid, prompt, out = a
-            consumed = len(out) and None
-            if prompt:
-                feed[s, 0] = prompt.pop(0)
-            # else keep feeding last generated token (already in `tok`)
-            elif len(out):
-                feed[s, 0] = out[-1]
-        nxt, logits, cache = dec(params, cache, jnp.asarray(feed),
-                                 jnp.int32(pos))
-        nxt = np.asarray(nxt)
-        for s, a in enumerate(active):
-            if a is None:
-                continue
-            rid, prompt, out = a
-            if not prompt:               # prompt consumed: we are generating
-                out.append(int(nxt[s, 0]))
-                if len(out) >= MAX_NEW:
-                    results[rid] = out
-                    active[s] = None     # slot freed for the next request
-        pos += 1
-
-    dt = time.time() - t0
-    for rid in sorted(results):
-        print(f"request {rid}: {results[rid]}")
-    print(f"served {len(results)} requests in {dt:.1f}s "
-          f"({pos} decode steps, {SLOTS} slots, continuous batching)")
+    out = serve.run(["--arch", "smollm-360m", "--requests", "10",
+                     "--slots", "4", "--max-new", "12", "--max-len", "64"])
+    for rid, r in enumerate(out["requests"]):
+        print(f"request {rid}: {r['tokens']}")
+    print(f"served {len(out['requests'])} requests in {out['wall_s']:.1f}s "
+          f"({out['decode_steps']} decode steps, 4 slots, continuous "
+          "batching)")
 
 
 if __name__ == "__main__":

@@ -32,7 +32,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Tuple
 
-from repro.launch.roofline import ICI_BW, ICI_LINKS
+from repro.launch.roofline import V5E
 
 OVERLAY_BW = 100e9          # B/s shared broadcast plane, per pod
 MULTICAST_OPS = ("all-gather", "all-gather-start", "all-to-all",
@@ -87,7 +87,7 @@ def eligible_volume(flows: List[CollectiveFlow],
 
 def wired_time(flows: List[CollectiveFlow], offloaded: float = 0.0) -> float:
     total = sum(f.wired_link_bytes for f in flows)
-    return max(0.0, total - offloaded) / (ICI_LINKS * ICI_BW)
+    return max(0.0, total - offloaded) / (V5E.ici_links * V5E.ici_bw)
 
 
 def overlay_time(volume: float, pcfg: PlaneConfig) -> float:
@@ -150,7 +150,7 @@ def balance_cell(coll_per_op: Dict[str, float], t_compute: float,
     flows = flows_from_coll_per_op(coll_per_op, pcfg.ring_radius)
     L = sum(f.wired_link_bytes for f in flows)
     elig = eligible_volume(flows, pcfg)
-    b_ici = ICI_LINKS * ICI_BW
+    b_ici = V5E.ici_links * V5E.ici_bw
     v_star = L * overlay_bw / (b_ici + overlay_bw)
     v = min(v_star, elig)
     t_wired = wired_time(flows)

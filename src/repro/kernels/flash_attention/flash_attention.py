@@ -9,8 +9,10 @@ Design for TPU (DESIGN.md hardware-adaptation):
 - GQA without materialising repeated KV: the K/V BlockSpec index_map folds
   the query head -> kv head mapping (h // group), so each KV block is
   fetched once per group from HBM.
-- masking (causal + sliding window) is computed from position vectors that
-  ride along as tiny VMEM blocks — the kernel never touches an S x S mask.
+- masking (causal + sliding window) is computed from positions that ride
+  along as tiny 2-D VMEM blocks, (BQ, 1) for queries and (1, BK) for keys
+  (Mosaic refuses 1-D int32 blocks), so the kernel never touches an
+  S x S mask.
 
 Oracle: ref.py (pure jnp); parity across shapes/dtypes is asserted in
 tests/test_kernels.py with interpret=True on CPU.
@@ -50,14 +52,13 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     if softcap is not None:
         s = jnp.tanh(s / softcap) * softcap
 
-    qp = qpos_ref[...].astype(jnp.int32)           # (BQ,)
-    kp = kpos_ref[...].astype(jnp.int32)           # (BK,)
-    mask = jnp.ones((q.shape[0], k.shape[0]), jnp.bool_)
     if causal:
-        mask = kp[None, :] <= qp[:, None]
+        qp = qpos_ref[...]                         # (BQ, 1)
+        kp = kpos_ref[...]                         # (1, BK)
+        mask = kp <= qp
         if window is not None:
-            mask &= (qp[:, None] - kp[None, :]) < window
-    s = jnp.where(mask, s, NEG_INF)
+            mask &= (qp - kp) < window
+        s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=1))
@@ -81,7 +82,7 @@ def flash_attention_kernel(q, k, v, q_pos, k_pos, *, scale: float,
                            causal: bool = True,
                            window: Optional[int] = None,
                            softcap: Optional[float] = None,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """q: (B, H, Sq, D); k/v: (B, K, Sk, D); positions int32 (Sq,), (Sk,).
 
     Sq/Sk must be multiples of 128 and D a multiple of 8 (the ops.py
@@ -99,8 +100,8 @@ def flash_attention_kernel(q, k, v, q_pos, k_pos, *, scale: float,
                           window=window, softcap=softcap, nk=nk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((BQ,), lambda b, h, iq, ik: (iq,)),
-            pl.BlockSpec((BK,), lambda b, h, iq, ik: (ik,)),
+            pl.BlockSpec((BQ, 1), lambda b, h, iq, ik: (iq, 0)),
+            pl.BlockSpec((1, BK), lambda b, h, iq, ik: (0, ik)),
             pl.BlockSpec((1, 1, BQ, D), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, BK, D),
                          lambda b, h, iq, ik: (b, h // G, ik, 0)),
@@ -116,5 +117,5 @@ def flash_attention_kernel(q, k, v, q_pos, k_pos, *, scale: float,
             pltpu.VMEM((BQ, D), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
-    )(q_pos, k_pos, q, k, v)
+    )(q_pos.reshape(Sq, 1), k_pos.reshape(1, Sk), q, k, v)
     return out

@@ -20,7 +20,7 @@ from .flash_attention import BK, BQ, flash_attention_kernel
 def flash_attention(q, k, v, q_pos, k_pos, window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     scale: Optional[float] = None, causal: bool = True,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """q: (B, S, H, D); k/v: (B, T, K, D); positions int32. -> (B, S, H, D).
 
     custom_vjp: the forward pass is the Pallas kernel; the backward pass
@@ -35,7 +35,7 @@ def flash_attention(q, k, v, q_pos, k_pos, window: Optional[int] = None,
                                              "causal", "interpret"))
 def _flash_attention_fwd_impl(q, k, v, q_pos, k_pos, window=None,
                               softcap=None, scale=None, causal=True,
-                              interpret=True) -> jnp.ndarray:
+                              interpret=False) -> jnp.ndarray:
     B, S, H, D = q.shape
     T = k.shape[1]
     scale = D ** -0.5 if scale is None else scale

@@ -9,7 +9,7 @@ from .rmsnorm import BR, rmsnorm_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def rmsnorm(x, scale, *, eps: float = 1e-6, interpret: bool = True):
+def rmsnorm(x, scale, *, eps: float = 1e-6, interpret: bool = False):
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     pad = (-x2.shape[0]) % BR

@@ -25,7 +25,7 @@ def _kernel(x_ref, scale_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))
 def rmsnorm_kernel(x, scale, *, eps: float = 1e-6,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool = False) -> jnp.ndarray:
     """x: (R, d) rows; scale: (d,). R padded to the row block by ops.py."""
     R, d = x.shape
     grid = (R // BR,)

@@ -11,7 +11,7 @@ from .ssd import ssd_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = True):
+def ssd(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False):
     """x: (b, L, H, P); dt: (b, L, H); A: (H,); B/C: (b, L, N).
     Returns (y (b, L, H, P), None)."""
     b, L, H, P = x.shape

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this:
@@ -13,14 +10,16 @@ For each cell this:
      (DESIGN.md S7),
 and writes one JSON per cell under experiments/dryrun/.
 
-Usage:
-  PYTHONPATH=src python -m repro.launch.dryrun --arch smollm-360m \
-      --shape train_4k --mesh pod
-  PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
+Usage (main() gives the CPU backend 512 placeholder devices):
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.dryrun \
+      --arch smollm-360m --shape train_4k --mesh pod
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.dryrun --all \
+      --mesh both
 """
 
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -30,8 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCHS, SHAPES, cells, get_arch
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.launch.mesh import make_production_mesh, use_mesh
-from repro.launch.roofline import cost_analysis
+from repro.launch.mesh import make_production_mesh
 from repro.launch import roofline as RL
 from repro.launch.unit_programs import (decode_unit_programs,
                                         train_unit_programs)
@@ -104,7 +102,7 @@ def lower_train_cell(cfg: ModelConfig, shape: ShapeConfig, mesh,
         st_sh = sharding_overrides(mesh, abstract_state, st_sh)
     batch = input_specs(cfg, shape)
     b_sh = logical_batch_shardings(mesh, batch)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             step_fn, in_shardings=(st_sh, b_sh),
             out_shardings=(st_sh, NamedSharding(mesh, P())),
@@ -127,7 +125,7 @@ def lower_prefill_cell(cfg: ModelConfig, shape: ShapeConfig, mesh,
         logits, _ = model.apply(params, batch)
         return logits[:, -1]
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(prefill, in_shardings=(p_sh, b_sh)).lower(
             abstract_params, batch)
         compiled = lowered.compile()
@@ -148,7 +146,7 @@ def lower_decode_cell(cfg: ModelConfig, shape: ShapeConfig, mesh,
     def serve_step(params, cache, token, pos):
         return model.decode(params, cache, token, pos)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             serve_step,
             in_shardings=(p_sh, c_sh, t_sh, rep),
@@ -184,7 +182,7 @@ def lower_unit(fn, abstract_args, mesh):
         if getattr(a, "ndim", 0) >= 2
         else NamedSharding(mesh, P())
         for a in abstract_args)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=shardings).lower(*abstract_args)
         return lowered.compile()
 
@@ -216,7 +214,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                   cfg, shape, mesh, attention_impl,
                   train_overrides=train_overrides)
           result["memory"] = _mem_dict(compiled.memory_analysis())
-          ca = cost_analysis(compiled)
+          ca = compiled.cost_analysis()
           result["cost_analysis"] = {k: float(v) for k, v in ca.items()
                                      if isinstance(v, (int, float))}
 
@@ -276,6 +274,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 
 
 def main():
+    # before the first device query: the CPU backend reads it once
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -1,67 +1,32 @@
-"""Production mesh construction.
+"""Mesh construction and the persistent compile cache.
 
-A FUNCTION (not a module-level constant) so importing this module never
+FUNCTIONS (not module-level constants) so importing this module never
 touches jax device state; the dry-run sets XLA_FLAGS before first init.
 """
 
 from __future__ import annotations
 
+import os
+
 import jax
 
-
-def make_auto_mesh(shape, axes):
-    """`jax.make_mesh` with Auto axis types across jax versions.
-
-    Newer jax exposes `jax.sharding.AxisType` and `make_mesh` takes
-    `axis_types`; on older versions (<= 0.4.x) every axis is Auto by
-    default and the parameter does not exist.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+# <checkout>/.jax_cache: a fixed path, since the path is part of the key
+CACHE_DIR = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                         "..", "..", ".jax_cache"))
 
 
-def use_mesh(mesh):
-    """`jax.set_mesh(mesh)` across jax versions.
-
-    Older jax (<= 0.4.x) has no `jax.set_mesh`; there the `Mesh` object
-    itself is the context manager that installs the ambient mesh.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+def use_compile_cache() -> None:
+    """Keep compiled programs across processes; call before the first
+    compile.  Where JAX_COMPILATION_CACHE_DIR is set, jax already reads it
+    and nothing is changed; otherwise the cache goes to CACHE_DIR."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
 
 
-def get_abstract_mesh():
-    """`jax.sharding.get_abstract_mesh()` across jax versions.
-
-    Older jax has no abstract-mesh tracking; there the ambient mesh
-    installed by the `Mesh` context manager is the equivalent.  Both
-    expose `.shape` as an axis-name -> size mapping (empty when no mesh
-    is active), which is all callers rely on.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax._src import mesh as mesh_lib
-    return mesh_lib.thread_resources.env.physical_mesh
-
-
-def shard_map(f, **kwargs):
-    """`jax.shard_map` across jax versions.
-
-    On 0.4.x it lives in `jax.experimental.shard_map` and the replication
-    check is spelled `check_rep` instead of `check_vma`.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return fn(f, **kwargs)
+def make_auto_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with every axis Auto (GSPMD decides the collectives)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -74,6 +39,12 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh():
-    """Whatever this host offers (tests / examples on CPU)."""
+    """Every device of this host on the model axis: (data=1, model=n)."""
     n = len(jax.devices())
     return make_auto_mesh((1, n), ("data", "model"))
+
+
+def make_one_device_mesh():
+    """(data=1, model=1) on the first device: the unsharded reference."""
+    return make_auto_mesh((1, 1), ("data", "model"),
+                          devices=jax.devices()[:1])

@@ -2,9 +2,11 @@
 
 Three terms per (arch x shape x mesh) cell, per DESIGN.md S7:
 
-    t_compute = FLOPs_per_device / PEAK_FLOPS
-    t_memory  = bytes_per_device / HBM_BW
-    t_coll    = collective_bytes_per_device / (ICI_LINKS * ICI_BW)
+    t_compute = FLOPs_per_device / peak FLOP/s
+    t_memory  = bytes_per_device / HBM bandwidth
+    t_coll    = collective_bytes_per_device / (ICI links * ICI bandwidth)
+
+with the peaks of the dry run's target chip, TPU v5e (`V5E`).
 
 `cost_analysis()` on this jax/XLA reports per-device cost and counts a
 while (scan) body ONCE (verified in tests/test_roofline.py), so callers
@@ -22,11 +24,33 @@ import dataclasses
 import re
 from typing import Dict
 
-# TPU v5e constants (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # B/s
-ICI_BW = 50e9                # B/s per link
-ICI_LINKS = 4                # usable links per chip in a 2D torus slice
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float                  # bf16 FLOP/s
+    hbm_bw: float                 # B/s
+    ici_bw: float                 # B/s per link
+    ici_links: int                # usable links per chip in a 2D torus slice
+
+
+# Published per-chip peaks, keyed by `jax.Device.device_kind`.
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
+    # at 819 GB/s, 1,600 Gbit/s of interchip interconnect (4 x 50 GB/s).
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                             ici_links=4),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of one chip; an unlisted kind is an error."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to PEAKS with a source")
+    return PEAKS[device_kind]
+
+
+V5E = peaks("TPU v5 lite")        # the production meshes' chip
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1,
@@ -110,15 +134,15 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / V5E.flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / V5E.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_link_bytes / (ICI_LINKS * ICI_BW)
+        return self.coll_link_bytes / (V5E.ici_links * V5E.ici_bw)
 
     @property
     def dominant(self) -> str:
@@ -141,28 +165,16 @@ class Roofline:
         }
 
 
-def cost_analysis(compiled) -> Dict:
-    """`Compiled.cost_analysis()` normalized across jax versions.
-
-    Older jax returns a list with one per-device dict, newer jax the
-    dict itself; either may be empty/None for trivial programs.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def extract(compiled, n_units: int = 1,
             unit_compiled=None) -> Roofline:
     """Roofline terms from compiled artifacts with scan-body extrapolation:
     total = full + (n_units - 1) * unit."""
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     coll = collective_bytes(compiled.as_text())
     if unit_compiled is not None and n_units > 1:
-        uca = cost_analysis(unit_compiled)
+        uca = unit_compiled.cost_analysis()
         ucoll = collective_bytes(unit_compiled.as_text())
         k = n_units - 1
         flops += k * float(uca.get("flops", 0.0))

@@ -169,7 +169,7 @@ def decode_unit_programs(cfg: ModelConfig, abstract_params, abstract_cache,
 
         def mamba_fn(p, c, xx):
             h = rmsnorm(p["norm"], xx, cfg.norm_eps)
-            y, c = decode_mamba(p["mamba"], h, c, cfg)
+            y, c = decode_mamba(p["mamba"], h, c, cfg, pos)
             return xx + y, c
 
         def shared_fn(p, c, xx):

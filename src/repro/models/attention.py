@@ -58,12 +58,14 @@ def _project_qkv(params: Params, x: jnp.ndarray, cfg: ModelConfig
 
 def _mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray,
           window: Optional[int], causal: bool = True) -> jnp.ndarray:
-    """(..., S, T) boolean: causal, optionally sliding-window."""
+    """(S, T) boolean from (S,) / (T,) positions, or (B, S, T) from
+    per-sequence (B, S) / (B, T) positions: causal, optionally
+    sliding-window."""
     if not causal:
-        return jnp.ones((q_pos.shape[0], k_pos.shape[0]), bool)
-    m = k_pos[None, :] <= q_pos[:, None]
+        return jnp.ones((1, 1), bool)
+    m = k_pos[..., None, :] <= q_pos[..., :, None]
     if window is not None:
-        m &= (q_pos[:, None] - k_pos[None, :]) < window
+        m &= (q_pos[..., :, None] - k_pos[..., None, :]) < window
     return m
 
 
@@ -81,7 +83,10 @@ def sdpa_naive(q, k, v, q_pos, k_pos, window, softcap, scale,
     qg = q.reshape(B, S, K, H // K, D)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, k).astype(jnp.float32)
     scores = _softcap(scores * scale, softcap)
-    scores = jnp.where(_mask(q_pos, k_pos, window, causal), scores, NEG_INF)
+    mask = _mask(q_pos, k_pos, window, causal)
+    if mask.ndim == 3:                 # per-sequence: (B, 1, 1, S, T)
+        mask = mask[:, None, None]
+    scores = jnp.where(mask, scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", p, v)
     return out.reshape(B, S, H, D)
@@ -184,28 +189,33 @@ def decode_attention(params: Params, x: jnp.ndarray, cache: Dict,
                      window: Optional[int] = None,
                      cross: bool = False
                      ) -> Tuple[jnp.ndarray, Dict]:
-    """One-token decode. x: (B, 1, d); pos: scalar int32 position.
+    """One-token decode. x: (B, 1, d); pos: (B,) int32, each sequence's
+    own position (a scalar is shared by all).
 
     The cache is a ring buffer of length min(max_len, window): sub-quadratic
-    long-context decode for SWA layers holds O(window) state.
+    long-context decode for SWA layers holds O(window) state.  Each row
+    holds one sequence, which writes its own ring slot and sees only the
+    slots it has written since its position 0, so a row reused by a new
+    request never attends to what its predecessor left there.
     """
     B = x.shape[0]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     q, k_new, v_new = _project_qkv(params, x, cfg)
     L = cache["k"].shape[1]
     if not cross:
-        posv = jnp.full((1,), pos, jnp.int32)
         cos, sin = rope_frequencies(cfg.head_dim, cfg.rope_fraction,
-                                    cfg.rope_theta, posv)
+                                    cfg.rope_theta, pos[:, None])
         q = apply_rope(q, cos, sin, cfg.rope_fraction)
         k_new = apply_rope(k_new, cos, sin, cfg.rope_fraction)
         slot = jnp.mod(pos, L)
-        ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot, 1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, 1)
+        rows = jnp.arange(B)
+        ck = cache["k"].at[rows, slot].set(k_new[:, 0])
+        cv = cache["v"].at[rows, slot].set(v_new[:, 0])
         cache = {"k": ck, "v": cv}
-        # absolute positions held in each ring slot
-        slots = jnp.arange(L, dtype=jnp.int32)
-        wrap = (pos // L) * L
-        k_pos = jnp.where(slots <= jnp.mod(pos, L), wrap + slots,
+        # absolute positions held in each ring slot, per sequence
+        slots = jnp.arange(L, dtype=jnp.int32)[None, :]
+        wrap = ((pos // L) * L)[:, None]
+        k_pos = jnp.where(slots <= slot[:, None], wrap + slots,
                           wrap - L + slots)
         k_pos = jnp.where(k_pos < 0, jnp.iinfo(jnp.int32).max, k_pos)
     else:
@@ -214,8 +224,7 @@ def decode_attention(params: Params, x: jnp.ndarray, cache: Dict,
         ck, cv = cache["k"], cache["v"]
         k_pos = jnp.arange(L, dtype=jnp.int32)
     scale = cfg.head_dim ** -0.5
-    q_pos = jnp.full((1,), pos, jnp.int32)
-    out = sdpa_naive(q, ck, cv, q_pos, k_pos, window, cfg.attn_softcap,
-                     scale, causal=not cross)
+    out = sdpa_naive(q, ck, cv, pos[:, None], k_pos, window,
+                     cfg.attn_softcap, scale, causal=not cross)
     y = jnp.einsum("bse,ed->bsd", out.reshape(B, 1, -1), params["wo"])
     return y, cache

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.launch.mesh import get_abstract_mesh, shard_map
 
 from .layers import _dense_init
 
@@ -71,7 +70,7 @@ def moe_block(params: Params, x: jnp.ndarray, cfg: ModelConfig
     from repro.runtime.parallel import get_context
     ctx = get_context()
     if ctx is not None:
-        mesh = get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if ctx.expert_axis in getattr(mesh, "shape", {}):
             n_e = mesh.shape[ctx.expert_axis]
             n_d = 1
@@ -176,7 +175,7 @@ def moe_block_expert_parallel(params, x, cfg: ModelConfig, ctx):
     to their expert's shard over an explicit all_to_all and return — the
     multicast-shaped traffic the paper's hybrid plane offloads."""
     from jax.sharding import PartitionSpec as P
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     ax = ctx.expert_axis
     n_e = mesh.shape[ax]
     data_axes = tuple(a for a in ("pod",) + tuple(ctx.data_axes)
@@ -225,7 +224,7 @@ def moe_block_expert_parallel(params, x, cfg: ModelConfig, ctx):
         aux = jax.lax.pmean(aux, (*data_axes, ax))
         return y, aux
 
-    shard = shard_map(
+    shard = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(ax, None, None), P(ax, None, None), P(ax, None, None),
                   P(None, None), tok_spec),
@@ -241,7 +240,7 @@ def moe_block_tp_ff(params, x, cfg: ModelConfig, ctx):
     mixtral where E < n_shards): rows stay put, every model shard computes
     its ff-slice for every row, partial results psum over the model axis."""
     from jax.sharding import PartitionSpec as P
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     ax = ctx.expert_axis
     data_axes = tuple(a for a in ("pod",) + tuple(ctx.data_axes)
                       if a in mesh.shape)
@@ -264,7 +263,7 @@ def moe_block_tp_ff(params, x, cfg: ModelConfig, ctx):
         aux = jax.lax.pmean(aux, (*data_axes, ax))
         return y, aux
 
-    shard = shard_map(
+    shard = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(None, None, ax), P(None, None, ax), P(None, ax, None),
                   P(None, None), P(data_axes, None)),

@@ -173,15 +173,20 @@ def init_ssm_cache(cfg: ModelConfig, batch: int) -> Dict[str, jnp.ndarray]:
 
 
 def decode_mamba(params: Params, x: jnp.ndarray, cache: Dict,
-                 cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict]:
-    """Single-token step. x: (B, 1, d)."""
+                 cfg: ModelConfig, pos: jnp.ndarray
+                 ) -> Tuple[jnp.ndarray, Dict]:
+    """Single-token step. x: (B, 1, d); pos: (B,) int32 (or a shared
+    scalar).  A row at position 0 starts a new sequence from zero state,
+    whatever the previous occupant of that row left in the cache."""
     B_ = x.shape[0]
+    fresh = jnp.broadcast_to(jnp.asarray(pos) == 0, (B_,))
     dssm, N, H, P = (cfg.d_inner, cfg.ssm_state, cfg.n_ssm_heads,
                      cfg.ssm_head_dim)
     z, xBC, dt = _split_proj(cfg, jnp.einsum("bld,de->ble", x,
                                              params["in_proj"]))
     xBC = xBC[:, 0]
-    window = jnp.concatenate([cache["conv"], xBC[:, None]], axis=1)
+    conv_prev = jnp.where(fresh[:, None, None], 0, cache["conv"])
+    window = jnp.concatenate([conv_prev, xBC[:, None]], axis=1)
     conv = (window * params["conv_w"]).sum(axis=1) + params["conv_b"]
     xBC = jax.nn.silu(conv.astype(jnp.float32)).astype(x.dtype)
     new_conv = window[:, 1:]
@@ -190,7 +195,8 @@ def decode_mamba(params: Params, x: jnp.ndarray, cache: Dict,
     A = -jnp.exp(params["A_log"])
     dA = jnp.exp(dtv * A)                                    # (B, H)
     xh = xs.reshape(B_, H, P)
-    st = cache["state"].astype(jnp.float32)
+    st = jnp.where(fresh[:, None, None, None], 0.0,
+                   cache["state"].astype(jnp.float32))
     st = st * dA[:, :, None, None] + jnp.einsum(
         "bh,bhp,bn->bhpn", dtv, xh.astype(jnp.float32),
         Bv.astype(jnp.float32))

@@ -194,7 +194,7 @@ def _decode_block(p, spec, cache_b, x, cfg, pos):
         y, cache_b = decode_attention(p["attn"], h, cache_b, cfg, pos,
                                       window=spec.window)
     elif spec.kind == "mamba":
-        y, cache_b = decode_mamba(p["mamba"], h, cache_b, cfg)
+        y, cache_b = decode_mamba(p["mamba"], h, cache_b, cfg, pos)
     elif spec.kind == "moe":
         y, _ = moe_block(p["moe"], h, cfg)
     else:
@@ -205,7 +205,8 @@ def _decode_block(p, spec, cache_b, x, cfg, pos):
 def decode_step(params: Params, cache: Params, token: jnp.ndarray,
                 pos: jnp.ndarray, cfg: ModelConfig
                 ) -> Tuple[jnp.ndarray, Params]:
-    """token: (B, 1) int32 (or (B, 1, d) embeddings); pos: scalar int32.
+    """token: (B, 1) int32 (or (B, 1, d) embeddings); pos: (B,) int32,
+    each row's position in its own sequence (a scalar is shared by all).
     Returns (logits (B, 1, V) fp32, new cache)."""
     if token.ndim == 2:
         x = embed(params["embed"], token, cfg)

@@ -132,6 +132,7 @@ def run_with_recovery(step_fn: Callable, state, n_steps: int,
                       checkpoint_every: int = 10,
                       failure_injector: Optional[Callable[[int], bool]] = None,
                       max_restarts: int = 25,
+                      start: int = 0,
                       ) -> Tuple[dict, List[RecoveryEvent], list]:
     """Driver loop with checkpoint/restart.  `failure_injector(step)` lets
     tests kill the run deterministically; production wires it to the
@@ -140,12 +141,12 @@ def run_with_recovery(step_fn: Callable, state, n_steps: int,
     Restores rewind `step` to the latest checkpoint, so any metrics
     recorded past that point are rolled back too (replayed steps would
     otherwise append duplicates); on success ``len(metrics_log) ==
-    n_steps`` exactly.  `max_restarts` bounds the retry loop: a
+    n_steps - start`` exactly, where `start` is the step `state` is at.  `max_restarts` bounds the retry loop: a
     deterministic injector that fires again at the restored step would
     otherwise spin forever."""
     events: List[RecoveryEvent] = []
     metrics_log = []
-    step = 0
+    step = start
     restarts = 0
     while step < n_steps:
         try:
@@ -168,6 +169,6 @@ def run_with_recovery(step_fn: Callable, state, n_steps: int,
             state, step = restore_fn()
             # roll the metrics log back with the state: entries for steps
             # >= the restore point are about to be replayed
-            del metrics_log[step:]
+            del metrics_log[max(0, step - start):]
             events.append(RecoveryEvent(step, "failure", [], ()))
     return state, events, metrics_log

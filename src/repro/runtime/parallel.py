@@ -43,8 +43,7 @@ def shard_batch(x):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import get_abstract_mesh
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if not getattr(mesh, "shape", None):
         return x
     axes = tuple(a for a in ("pod", *ctx.data_axes) if a in mesh.shape)

@@ -2,7 +2,7 @@
 
 `serve_step` is what the decode_* dry-run shapes lower: one new token per
 sequence against a KV cache of the cell's seq_len.  A tiny continuous-
-batching scheduler drives it in examples/serve_lm.py.
+batching scheduler drives it in launch/serve.py.
 """
 
 from __future__ import annotations

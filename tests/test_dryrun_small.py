@@ -12,8 +12,7 @@ from repro.runtime.sharding import (logical_batch_shardings,
                                     state_shardings)
 from repro.runtime.train import TrainConfig, make_train_step
 from repro.optim.optimizers import OptimizerConfig
-from repro.launch.mesh import make_auto_mesh, use_mesh
-from repro.launch.roofline import cost_analysis
+from repro.launch.mesh import make_auto_mesh
 
 
 def test_lower_compile_reduced_arch():
@@ -27,13 +26,13 @@ def test_lower_compile_reduced_arch():
     batch = {"tokens": jax.ShapeDtypeStruct((2, 32), jnp.int32),
              "labels": jax.ShapeDtypeStruct((2, 32), jnp.int32)}
     b_sh = logical_batch_shardings(mesh, batch)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step_fn, in_shardings=(st_sh, b_sh),
                            out_shardings=(st_sh, NamedSharding(mesh, P()))
                            ).lower(abstract_state, batch).compile()
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes > 0
-    assert cost_analysis(compiled).get("flops", 0) > 0
+    assert compiled.cost_analysis().get("flops", 0) > 0
 
 
 def test_dryrun_results_complete():

@@ -32,7 +32,7 @@ def test_flash_attention_matches_ref(dtype, B, S, T, H, K, D, causal,
     qp = jnp.arange(T - S, T, dtype=jnp.int32)
     kp = jnp.arange(T, dtype=jnp.int32)
     out = flash_attention(q, k, v, qp, kp, window=window, softcap=softcap,
-                          causal=causal)
+                          causal=causal, interpret=True)
     ref = attention_ref(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                         v.transpose(0, 2, 1, 3), qp, kp, scale=D ** -0.5,
                         causal=causal, window=window,
@@ -56,7 +56,7 @@ def test_ssd_matches_sequential_ref(dtype, b, L, H, P, N, chunk):
     A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
     B = (jax.random.normal(ks[3], (b, L, N)) * 0.5).astype(dtype)
     C = (jax.random.normal(ks[0], (b, L, N)) * 0.5).astype(dtype)
-    y, _ = ssd(x, dt, A, B, C, chunk=chunk)
+    y, _ = ssd(x, dt, A, B, C, chunk=chunk, interpret=True)
     y_ref, _ = ssd_ref(x.astype(jnp.float32), dt, A,
                        B.astype(jnp.float32), C.astype(jnp.float32))
     tol = 1e-3 if dtype == jnp.float32 else 1e-1
@@ -70,7 +70,7 @@ def test_ssd_matches_sequential_ref(dtype, b, L, H, P, N, chunk):
 def test_rmsnorm_matches_ref(dtype, shape):
     x = jax.random.normal(jax.random.PRNGKey(2), shape, dtype)
     s = jnp.asarray(np.linspace(0.5, 1.5, shape[-1]), dtype)
-    out = rmsnorm(x, s)
+    out = rmsnorm(x, s, interpret=True)
     ref = rmsnorm_ref(x, s)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=2e-2)
@@ -84,7 +84,7 @@ def test_flash_attention_grad_flows():
     pos = jnp.arange(128, dtype=jnp.int32)
 
     def f(q):
-        return flash_attention(q, kv, kv, pos, pos).sum()
+        return flash_attention(q, kv, kv, pos, pos, interpret=True).sum()
 
     g = jax.grad(f)(q)
     assert bool(jnp.isfinite(g).all()) and float(jnp.abs(g).max()) > 0

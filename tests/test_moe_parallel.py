@@ -19,7 +19,7 @@ from repro.configs import ARCHS, reduced
 from repro.models.moe import (moe_block_gspmd, moe_block_expert_parallel,
                               moe_block_tp_ff, moe_init)
 from repro.runtime.parallel import ParallelContext
-from repro.launch.mesh import make_auto_mesh, use_mesh
+from repro.launch.mesh import make_auto_mesh
 
 cfg = dataclasses.replace(reduced(ARCHS["kimi-k2-1t-a32b"]), n_experts=8,
                           experts_per_token=2, moe_d_ff=32, d_model=64,
@@ -28,7 +28,7 @@ params = moe_init(jax.random.PRNGKey(0), cfg)
 mesh = make_auto_mesh((2, 4), ("data", "model"))
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 64), jnp.float32)
 ctx = ParallelContext(capacity_factor=8.0)   # high capacity: no drops
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     y_ref, _ = jax.jit(lambda p, x: moe_block_gspmd(p, x, cfg))(params, x)
     y_ep, _ = jax.jit(
         lambda p, x: moe_block_expert_parallel(p, x, cfg, ctx))(params, x)

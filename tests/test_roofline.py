@@ -7,9 +7,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.launch.mesh import make_auto_mesh, use_mesh
-from repro.launch.roofline import (Roofline, collective_bytes,
-                                   cost_analysis, _type_bytes)
+from repro.launch.mesh import make_auto_mesh
+from repro.launch.roofline import (Roofline, collective_bytes, peaks,
+                                   _type_bytes)
 
 
 def test_type_bytes():
@@ -33,7 +33,7 @@ def test_collective_parser_finds_allreduce():
         return jnp.sum(x @ x.T)  # contraction over the sharded dim -> AR
 
     x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(f, in_shardings=sh).lower(x).compile()
     stats = collective_bytes(compiled.as_text())
     assert stats.payload_bytes > 0
@@ -51,7 +51,7 @@ def test_scan_body_counted_once():
             return y
         x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
         w = jax.ShapeDtypeStruct((64, 64), jnp.float32)
-        return cost_analysis(jax.jit(f).lower(x, w).compile())["flops"]
+        return jax.jit(f).lower(x, w).compile().cost_analysis()["flops"]
 
     assert make(2) == make(8)
 
@@ -76,3 +76,10 @@ def test_dominant_term():
     r2 = Roofline(flops=1.0, hbm_bytes=819e9 * 2, coll_link_bytes=1.0,
                   coll_per_op={})
     assert r2.dominant == "memory" and r2.step_time == pytest.approx(2.0)
+
+
+def test_peaks_keyed_by_device_kind():
+    assert peaks("TPU v5 lite").flops == 197e12
+    assert peaks("TPU v5 lite").hbm_bw == 819e9
+    with pytest.raises(KeyError, match="cpu"):
+        peaks("cpu")
