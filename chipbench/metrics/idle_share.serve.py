@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device,
+in %."""
+
+
+def read(r):
+    if r.kind != "serve" or r.trace.window_s <= 0 or r.trace.busy_s <= 0:
+        return None
+    return 100.0 * (1.0 - r.trace.busy_s / r.trace.window_s)
