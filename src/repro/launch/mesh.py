@@ -6,6 +6,7 @@ touches jax device state; the dry-run sets XLA_FLAGS before first init.
 
 from __future__ import annotations
 
+import contextlib
 import os
 
 import jax
@@ -21,6 +22,41 @@ def use_compile_cache() -> None:
     and nothing is changed; otherwise the cache goes to CACHE_DIR."""
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+
+
+# tracing, lowering, and compiling or fetching from the persistent cache
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                  "/jax/core/compile/backend_compile_duration")
+CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+CACHE_MISS = "/jax/compilation_cache/cache_misses"
+
+
+@contextlib.contextmanager
+def compile_work():
+    """Tally the compile work JAX reports while inside: `compile_s`, the
+    seconds of COMPILE_EVENTS (the backend's share includes fetches from
+    the persistent cache), `cache_retrieval_s`, those fetches alone, and
+    `cache_misses`, programs the cache did not hold."""
+    got = {"compile_s": 0.0, "cache_retrieval_s": 0.0, "cache_misses": 0}
+
+    def on_duration(event, secs, **_):
+        if event in COMPILE_EVENTS:
+            got["compile_s"] += secs
+        elif event == CACHE_RETRIEVAL:
+            got["cache_retrieval_s"] += secs
+
+    def on_event(event, **_):
+        if event == CACHE_MISS:
+            got["cache_misses"] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+    try:
+        yield got
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+        jax.monitoring.unregister_event_listener(on_event)
 
 
 def make_auto_mesh(shape, axes, devices=None):

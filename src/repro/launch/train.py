@@ -2,7 +2,8 @@
 
     PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \
         --steps 200 --batch 16 --seq 128 [--full] [--resume] \
-        [--mesh host|one|pod|multipod] [--compress] [--microbatches 4]
+        [--mesh host|one|pod|multipod] [--compress] [--microbatches 4] \
+        [--profile-dir DIR --profile-steps 2:4]
 
 Wires together everything the framework provides: mesh + sharding rules,
 the ParallelContext (expert-parallel MoE, batch-pinned activations),
@@ -12,9 +13,18 @@ pipeline, async checkpointing, straggler tracking, and crash recovery
 run fails).  The default is a reduced-width model; --full runs the
 published widths.  `run(argv)` is the in-process entry point: it returns
 the run's numbers, so a caller that holds the chip can drive it.
+
+Each step runs inside `jax.profiler.StepTraceAnnotation("train")`, and the
+checkpoint saves and restores inside the host spans `train.checkpoint`
+and `train.restore`.  `--profile-dir DIR` traces steps A to B-1 of
+`--profile-steps A:B` with `jax.profiler` into DIR and writes beside the
+trace `program_ops.json`: the compiled step's module name and each
+instruction's op_name, which names the model's scopes
+(`repro.models.scopes`) that the trace's device ops belong to.
 """
 
 import argparse
+import json
 import os
 import tempfile
 import time
@@ -26,8 +36,10 @@ from repro.checkpoint.checkpointer import (AsyncCheckpointer, latest_steps,
                                            restore)
 from repro.configs import ARCHS, reduced
 from repro.data.pipeline import DataConfig, batch_for_model
-from repro.launch.mesh import (make_host_mesh, make_one_device_mesh,
-                               make_production_mesh, use_compile_cache)
+from repro.launch.mesh import (compile_work, make_host_mesh,
+                               make_one_device_mesh, make_production_mesh,
+                               use_compile_cache)
+from repro.models.scopes import program_ops
 from repro.obs.metrics import get_logger
 from repro.units import MEGA
 from repro.optim.optimizers import OptimizerConfig
@@ -70,7 +82,40 @@ def parse_args(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--profile-dir", default=None,
+                    help="trace the --profile-steps steps into this directory")
+    ap.add_argument("--profile-steps", default="2:4", metavar="A:B",
+                    help="steps A to B-1 are traced")
     return ap.parse_args(argv)
+
+
+def span(name: str):
+    """A host span in the profiler's trace, on the device's clock."""
+    return jax.profiler.TraceAnnotation(name)
+
+
+class StepProfile:
+    """Traces the steps [first, last) once into `path`; inert without a
+    path."""
+
+    def __init__(self, path, steps: str):
+        self.path, self.active, self.done = path, False, path is None
+        self.first, self.last = (int(x) for x in steps.split(":"))
+
+    def before(self, step: int):
+        if not self.done and not self.active and (
+                self.first <= step < self.last):
+            jax.profiler.start_trace(self.path)
+            self.active = True
+
+    def after(self, step: int):
+        if self.active and step + 1 >= self.last:
+            self.stop()
+
+    def stop(self):
+        if self.active:
+            jax.profiler.stop_trace()
+            self.active, self.done = False, True
 
 
 def run(argv=None) -> dict:
@@ -113,30 +158,47 @@ def run(argv=None) -> dict:
         state = jit_init(jax.random.PRNGKey(0))
 
         ck = AsyncCheckpointer(args.ckpt_dir, keep=3)
+
+        def save(state, step):
+            with span("train.checkpoint"):
+                ck.save_async(state, step)
+
         start = 0
         if args.resume and latest_steps(args.ckpt_dir):
-            state = restore(args.ckpt_dir, state, shardings=st_sh)
+            with span("train.restore"):
+                state = restore(args.ckpt_dir, state, shardings=st_sh)
             start = int(jax.device_get(state["step"]))
             log.info(f"resumed at step {start}", step=start)
 
         t0 = time.perf_counter()
-        jit_step = jax.jit(step_fn, donate_argnums=0,
-                           in_shardings=(st_sh, None),
-                           out_shardings=(st_sh, None)
-                           ).lower(state, batch_at(start)).compile()
+        with compile_work() as work:
+            jit_step = jax.jit(step_fn, donate_argnums=0,
+                               in_shardings=(st_sh, None),
+                               out_shardings=(st_sh, None)
+                               ).lower(state, batch_at(start)).compile()
         compile_s = time.perf_counter() - t0
-        log.info(f"step program compiled in {compile_s:.1f}s",
-                 compile_s=compile_s)
+        log.info(f"step program compiled in {compile_s:.1f}s "
+                 f"({work['cache_misses']} compile cache misses)",
+                 compile_s=compile_s, cache_misses=work["cache_misses"])
+        profile = StepProfile(args.profile_dir, args.profile_steps)
+        if args.profile_dir:
+            os.makedirs(args.profile_dir, exist_ok=True)
+            with open(os.path.join(args.profile_dir,
+                                   "program_ops.json"), "w") as f:
+                json.dump(program_ops(jit_step.as_text()), f)
 
         straggler = StragglerMitigator()
         step_s = []                 # seconds of each step in metrics_log
 
         def one_step(state, batch):
             s = start + len(step_s)
+            profile.before(s)
             t0 = time.perf_counter()
-            state, metrics = jit_step(state, batch)
-            metrics = jax.device_get(metrics)   # waits for the device
+            with jax.profiler.StepTraceAnnotation("train", step_num=s):
+                state, metrics = jit_step(state, batch)
+                metrics = jax.device_get(metrics)   # waits for the device
             dt = time.perf_counter() - t0
+            profile.after(s)
             step_s.append(dt)
             straggler.record(0, dt)
             if s % args.log_every == 0 or s == args.steps - 1:
@@ -154,7 +216,9 @@ def run(argv=None) -> dict:
         def restore_latest():
             ck.wait()
             if latest_steps(args.ckpt_dir):
-                restored = restore(args.ckpt_dir, abstract, shardings=st_sh)
+                with span("train.restore"):
+                    restored = restore(args.ckpt_dir, abstract,
+                                       shardings=st_sh)
                 s = int(jax.device_get(restored["step"]))
             else:
                 restored, s = jit_init(jax.random.PRNGKey(0)), 0
@@ -163,11 +227,14 @@ def run(argv=None) -> dict:
             return restored, s
 
         t_run = time.perf_counter()
-        state, events, metrics_log = run_with_recovery(
-            one_step, state, args.steps, batch_at, ck.save_async,
-            restore_latest, checkpoint_every=args.ckpt_every,
-            max_restarts=MAX_RESTARTS, start=start)
-        ck.save_async(state, args.steps)
+        try:
+            state, events, metrics_log = run_with_recovery(
+                one_step, state, args.steps, batch_at, save,
+                restore_latest, checkpoint_every=args.ckpt_every,
+                max_restarts=MAX_RESTARTS, start=start)
+        finally:
+            profile.stop()
+        save(state, args.steps)
         ck.wait()
         wall_s = time.perf_counter() - t_run
         log.info(f"finished {args.steps - start} steps in {wall_s:.1f}s; "

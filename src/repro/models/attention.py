@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from .layers import _dense_init, apply_rope, rope_frequencies
+from .scopes import scope, scoped
 
 Params = Dict[str, jnp.ndarray]
 NEG_INF = -2.0 ** 30
@@ -136,17 +137,20 @@ def sdpa(q, k, v, q_pos, k_pos, window, softcap, scale,
          impl: str = "auto", causal: bool = True) -> jnp.ndarray:
     if impl == "auto":
         impl = "chunked" if k.shape[1] > 2048 else "naive"
-    if impl == "pallas":
-        from repro.kernels.flash_attention.ops import flash_attention
-        return flash_attention(q, k, v, q_pos, k_pos, window=window,
-                               softcap=softcap, scale=scale, causal=causal)
-    if impl == "chunked":
-        return sdpa_chunked(q, k, v, q_pos, k_pos, window, softcap, scale,
-                            causal=causal)
-    return sdpa_naive(q, k, v, q_pos, k_pos, window, softcap, scale,
-                      causal=causal)
+    with scope("sdpa"):
+        if impl == "pallas":
+            from repro.kernels.flash_attention.ops import flash_attention
+            return flash_attention(q, k, v, q_pos, k_pos, window=window,
+                                   softcap=softcap, scale=scale,
+                                   causal=causal)
+        if impl == "chunked":
+            return sdpa_chunked(q, k, v, q_pos, k_pos, window, softcap,
+                                scale, causal=causal)
+        return sdpa_naive(q, k, v, q_pos, k_pos, window, softcap, scale,
+                          causal=causal)
 
 
+@scoped("attn")
 def attention(params: Params, x: jnp.ndarray, cfg: ModelConfig,
               positions: jnp.ndarray, window: Optional[int] = None,
               impl: str = "auto", kv_override=None,
@@ -184,6 +188,7 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
             "v": jnp.zeros(shape, jnp.bfloat16)}
 
 
+@scoped("attn")
 def decode_attention(params: Params, x: jnp.ndarray, cache: Dict,
                      cfg: ModelConfig, pos: jnp.ndarray,
                      window: Optional[int] = None,
@@ -224,7 +229,8 @@ def decode_attention(params: Params, x: jnp.ndarray, cache: Dict,
         ck, cv = cache["k"], cache["v"]
         k_pos = jnp.arange(L, dtype=jnp.int32)
     scale = cfg.head_dim ** -0.5
-    out = sdpa_naive(q, ck, cv, pos[:, None], k_pos, window,
-                     cfg.attn_softcap, scale, causal=not cross)
+    with scope("sdpa"):
+        out = sdpa_naive(q, ck, cv, pos[:, None], k_pos, window,
+                         cfg.attn_softcap, scale, causal=not cross)
     y = jnp.einsum("bse,ed->bsd", out.reshape(B, 1, -1), params["wo"])
     return y, cache

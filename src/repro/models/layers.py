@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from .scopes import scoped
 
 Params = Dict[str, jnp.ndarray]
 DTYPE = jnp.bfloat16
@@ -80,6 +81,7 @@ def mlp_init(key, d: int, d_ff: int, activation: str) -> Params:
     return p
 
 
+@scoped("mlp")
 def mlp(params: Params, x: jnp.ndarray, activation: str) -> jnp.ndarray:
     up = jnp.einsum("...d,df->...f", x, params["w_up"])
     if activation in ("silu", "geglu"):

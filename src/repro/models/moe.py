@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 
 from .layers import _dense_init
+from .scopes import scoped
 
 Params = Dict[str, jnp.ndarray]
 
@@ -54,6 +55,7 @@ def route(params: Params, x2d: jnp.ndarray, cfg: ModelConfig
     return w.astype(x2d.dtype), idx, aux
 
 
+@scoped("moe")
 def moe_block(params: Params, x: jnp.ndarray, cfg: ModelConfig
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: (B, S, d) -> (y, aux_loss).

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from .layers import _dense_init, rmsnorm, rmsnorm_init
+from .scopes import scope, scoped
 
 Params = Dict[str, jnp.ndarray]
 
@@ -124,6 +125,7 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     return y, final.astype(x.dtype)
 
 
+@scoped("mamba")
 def mamba_block(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                 impl: str = "auto") -> jnp.ndarray:
     """Full-sequence Mamba2 block. x: (B, L, d) -> (B, L, d)."""
@@ -137,20 +139,21 @@ def mamba_block(params: Params, x: jnp.ndarray, cfg: ModelConfig,
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
     A = -jnp.exp(params["A_log"])
     xh = xs.reshape(B_, L, H, P)
-    if impl == "pallas":
-        from repro.kernels.ssd.ops import ssd
-        y, _ = ssd(xh, dt, A, Bv, Cv, chunk=cfg.ssm_chunk)
-    else:
-        # pad L to a chunk multiple for the scan
-        Q = min(cfg.ssm_chunk, max(16, L))
-        pad = (-L) % Q
-        if pad:
-            xh = jnp.pad(xh, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-            Bv = jnp.pad(Bv, ((0, 0), (0, pad), (0, 0)))
-            Cv = jnp.pad(Cv, ((0, 0), (0, pad), (0, 0)))
-        y, _ = ssd_scan(xh, dt, A, Bv, Cv, Q)
-        y = y[:, :L]
+    with scope("ssd"):
+        if impl == "pallas":
+            from repro.kernels.ssd.ops import ssd
+            y, _ = ssd(xh, dt, A, Bv, Cv, chunk=cfg.ssm_chunk)
+        else:
+            # pad L to a chunk multiple for the scan
+            Q = min(cfg.ssm_chunk, max(16, L))
+            pad = (-L) % Q
+            if pad:
+                xh = jnp.pad(xh, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+                Bv = jnp.pad(Bv, ((0, 0), (0, pad), (0, 0)))
+                Cv = jnp.pad(Cv, ((0, 0), (0, pad), (0, 0)))
+            y, _ = ssd_scan(xh, dt, A, Bv, Cv, Q)
+            y = y[:, :L]
     y = y + params["D"].astype(y.dtype)[:, None] * xs.reshape(B_, L, H, P)
     y = y.reshape(B_, L, dssm)
     y = rmsnorm(params["gate_norm"],
@@ -172,6 +175,7 @@ def init_ssm_cache(cfg: ModelConfig, batch: int) -> Dict[str, jnp.ndarray]:
     }
 
 
+@scoped("mamba")
 def decode_mamba(params: Params, x: jnp.ndarray, cache: Dict,
                  cfg: ModelConfig, pos: jnp.ndarray
                  ) -> Tuple[jnp.ndarray, Dict]:

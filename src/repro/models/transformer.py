@@ -23,6 +23,7 @@ from .attention import (attention, decode_attention, init_kv_cache,
 from .layers import (embed, embedding_init, mlp, mlp_init, rmsnorm,
                      rmsnorm_init, unembed)
 from .moe import moe_block, moe_init
+from .scopes import scope
 from .ssm import decode_mamba, init_ssm_cache, mamba_block, mamba_init
 
 Params = Dict[str, Any]
@@ -92,7 +93,8 @@ def _apply_block(p: Params, spec, x, cfg: ModelConfig, positions, impl,
                  aux):
     from repro.runtime.parallel import shard_batch
     x = shard_batch(x)
-    h = rmsnorm(p["norm"], x, cfg.norm_eps)
+    with scope("norm"):
+        h = rmsnorm(p["norm"], x, cfg.norm_eps)
     if spec.kind == "attn":
         y = attention(p["attn"], h, cfg, positions, window=spec.window,
                       impl=impl)
@@ -112,7 +114,8 @@ def forward(params: Params, inputs: jnp.ndarray, cfg: ModelConfig,
     """inputs: (B, S) int tokens, or (B, S, d) embeddings for frontend
     stubs.  Returns (logits fp32 (B, S, V), aux_loss scalar)."""
     if inputs.ndim == 2:
-        x = embed(params["embed"], inputs, cfg)
+        with scope("embed"):
+            x = embed(params["embed"], inputs, cfg)
     else:
         x = inputs.astype(jnp.bfloat16)
     S = x.shape[1]
@@ -127,9 +130,11 @@ def forward(params: Params, inputs: jnp.ndarray, cfg: ModelConfig,
                                      impl, 0.0)
                 return xc, None
             x, _ = jax.lax.scan(inner, x, unit_params)
-            h = rmsnorm(shared["norm1"], x, cfg.norm_eps)
+            with scope("norm"):
+                h = rmsnorm(shared["norm1"], x, cfg.norm_eps)
             x = x + attention(shared["attn"], h, cfg, positions, impl=impl)
-            h = rmsnorm(shared["norm2"], x, cfg.norm_eps)
+            with scope("norm"):
+                h = rmsnorm(shared["norm2"], x, cfg.norm_eps)
             x = x + mlp(shared["mlp"], h, cfg.activation)
             return x, 0.0
     else:
@@ -152,8 +157,10 @@ def forward(params: Params, inputs: jnp.ndarray, cfg: ModelConfig,
 
     (x, aux), _ = jax.lax.scan(scan_body, (x, jnp.zeros((), jnp.float32)),
                                params["units"])
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return unembed(params["embed"], x, cfg), aux
+    with scope("norm"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    with scope("head"):
+        return unembed(params["embed"], x, cfg), aux
 
 
 # --------------------------------------------------------------------------
@@ -189,7 +196,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
 
 
 def _decode_block(p, spec, cache_b, x, cfg, pos):
-    h = rmsnorm(p["norm"], x, cfg.norm_eps)
+    with scope("norm"):
+        h = rmsnorm(p["norm"], x, cfg.norm_eps)
     if spec.kind == "attn":
         y, cache_b = decode_attention(p["attn"], h, cache_b, cfg, pos,
                                       window=spec.window)
@@ -209,7 +217,8 @@ def decode_step(params: Params, cache: Params, token: jnp.ndarray,
     each row's position in its own sequence (a scalar is shared by all).
     Returns (logits (B, 1, V) fp32, new cache)."""
     if token.ndim == 2:
-        x = embed(params["embed"], token, cfg)
+        with scope("embed"):
+            x = embed(params["embed"], token, cfg)
     else:
         x = token.astype(jnp.bfloat16)
 
@@ -224,11 +233,13 @@ def decode_step(params: Params, cache: Params, token: jnp.ndarray,
                 xc, cb = _decode_block(mp, cfg.unit[0], cb, xc, cfg, pos)
                 return xc, cb
             x, new_inner = jax.lax.scan(inner, x, (unit_params, cache_u))
-            h = rmsnorm(shared["norm1"], x, cfg.norm_eps)
+            with scope("norm"):
+                h = rmsnorm(shared["norm1"], x, cfg.norm_eps)
             y, shared_kv = decode_attention(shared["attn"], h, shared_kv,
                                             cfg, pos)
             x = x + y
-            h = rmsnorm(shared["norm2"], x, cfg.norm_eps)
+            with scope("norm"):
+                h = rmsnorm(shared["norm2"], x, cfg.norm_eps)
             x = x + mlp(shared["mlp"], h, cfg.activation)
             return x, (new_inner, shared_kv)
 
@@ -251,5 +262,7 @@ def decode_step(params: Params, cache: Params, token: jnp.ndarray,
                                     (params["units"], cache["units"]))
         new_cache = {"units": new_units}
 
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    return unembed(params["embed"], x, cfg), new_cache
+    with scope("norm"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    with scope("head"):
+        return unembed(params["embed"], x, cfg), new_cache

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import build_model
+from repro.models.scopes import scope
 from repro.optim.optimizers import OptimizerConfig, build_optimizer
 from .compression import CompressionConfig, compress_decompress
 
@@ -58,8 +59,9 @@ def make_loss_fn(cfg: ModelConfig, tcfg: TrainConfig):
 
     def loss_fn(params, batch):
         logits, aux = model.apply(params, batch)
-        loss, ce = cross_entropy(logits, batch["labels"],
-                                 tcfg.z_loss_weight, tcfg.loss_impl)
+        with scope("head"):
+            loss, ce = cross_entropy(logits, batch["labels"],
+                                     tcfg.z_loss_weight, tcfg.loss_impl)
         total = loss + tcfg.aux_loss_weight * aux
         return total, {"ce": ce, "aux": aux}
 
@@ -105,9 +107,10 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig):
     def train_step(state, batch):
         params, opt_state, step = state["params"], state["opt"], state["step"]
         loss, metrics, grads = compute_grads(params, batch)
-        if tcfg.compression is not None:
-            grads = compress_decompress(grads, tcfg.compression)
-        new_params, new_opt = opt.update(grads, opt_state, params, step)
+        with scope("optimizer"):
+            if tcfg.compression is not None:
+                grads = compress_decompress(grads, tcfg.compression)
+            new_params, new_opt = opt.update(grads, opt_state, params, step)
         metrics = dict(metrics, loss=loss)
         return {"params": new_params, "opt": new_opt, "step": step + 1}, \
             metrics

@@ -90,3 +90,29 @@ def test_compile_cache_dir_placed_from_outside(monkeypatch):
     assert mesh.CACHE_DIR == os.path.join(root, ".jax_cache")
     with open(os.path.join(root, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
+
+
+def test_train_profile_writes_one_trace_with_spans(tmp_path):
+    import glob
+    import json
+
+    from jax.profiler import ProfileData
+
+    from repro.models.scopes import top_scope
+    prof = tmp_path / "profile"
+    train.run(_train_argv(tmp_path / "ckpt", 6)
+              + ["--profile-dir", str(prof), "--profile-steps", "1:5"])
+    paths = glob.glob(str(prof / "**" / "*.xplane.pb"), recursive=True)
+    assert len(paths) == 1
+    names = [e.name for p in ProfileData.from_file(paths[0]).planes
+             if p.name.startswith("/host") for line in p.lines
+             for e in line.events]
+    # steps 1-4 traced; saves after steps 2 and 4, none restored
+    assert names.count("train") == 4
+    assert names.count("train.checkpoint") == 2
+    assert "train.restore" not in names
+    with open(prof / "program_ops.json") as f:
+        program = json.load(f)
+    assert program["module"] == "jit_train_step"
+    assert {top_scope(n) for n in program["ops"].values()} >= {
+        "embed", "norm", "attn", "mlp", "head", "optimizer"}
