@@ -6,10 +6,14 @@ Three interchangeable inner implementations, all numerically equivalent
 
 - "naive":   materialises (B, K, G, S, T) scores — smoke tests / short seq.
 - "chunked": lax.scan over KV chunks with an online softmax — O(S*chunk)
-             memory, the default for long sequences (this is what makes the
+             memory, for long sequences off the TPU (this is what makes the
              long-context cells lowerable without an S x S buffer).
-- "pallas":  the flash-attention TPU kernel from repro.kernels (VMEM-tiled);
-             validated in interpret mode on CPU.
+- "pallas":  the flash-attention TPU kernels from repro.kernels (VMEM-tiled
+             forward and backward); validated in interpret mode on CPU.
+
+`impl="auto"` picks "pallas" on the TPU for key lengths from PALLAS_MIN_T
+up when the call is not partitioned over a multi-device mesh, else
+"chunked" above 2048 keys and "naive" below.
 """
 
 from __future__ import annotations
@@ -25,6 +29,10 @@ from .scopes import scope, scoped
 
 Params = Dict[str, jnp.ndarray]
 NEG_INF = -2.0 ** 30
+# the flash kernels beat the plain XLA paths, forward + backward, at every
+# key length that chip_smoke.py's sdpa phase measures on one TPU v5e (512
+# to 4096); shorter sequences keep the plain paths, unmeasured
+PALLAS_MIN_T = 512
 
 
 def attention_init(key, cfg: ModelConfig) -> Params:
@@ -133,10 +141,27 @@ def sdpa_chunked(q, k, v, q_pos, k_pos, window, softcap, scale,
     return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, D).astype(q.dtype)
 
 
+def _one_device() -> bool:
+    """Whether the call runs unpartitioned: Mosaic kernels have no
+    partitioning rule, so a multi-device mesh cannot compile them."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh.empty or mesh.size == 1
+
+
+def auto_impl(T: int, q_pos: jnp.ndarray) -> str:
+    """What impl="auto" runs for T keys: from the backend, the mesh and
+    the shapes.  The kernels take one position vector shared by the
+    batch."""
+    if (jax.default_backend() == "tpu" and T >= PALLAS_MIN_T
+            and q_pos.ndim == 1 and _one_device()):
+        return "pallas"
+    return "chunked" if T > 2048 else "naive"
+
+
 def sdpa(q, k, v, q_pos, k_pos, window, softcap, scale,
          impl: str = "auto", causal: bool = True) -> jnp.ndarray:
     if impl == "auto":
-        impl = "chunked" if k.shape[1] > 2048 else "naive"
+        impl = auto_impl(k.shape[1], q_pos)
     with scope("sdpa"):
         if impl == "pallas":
             from repro.kernels.flash_attention.ops import flash_attention

@@ -22,24 +22,75 @@ from repro.kernels.ssd.ref import ssd_ref
     (1, 128, 384, 4, 2, 64, True, None, None),    # longer KV (decode-ish)
     (1, 128, 128, 4, 1, 64, False, None, None),   # MQA + non-causal
     (1, 130, 130, 2, 2, 32, True, None, None),    # awkward sizes
+    (1, 520, 520, 4, 2, 64, True, None, None),    # S not a block multiple
+    (1, 100, 300, 2, 2, 64, True, None, None),    # T > S, offset queries
+    (1, 640, 640, 2, 2, 64, True, 100, None),     # window < one block
+    (1, 256, 256, 4, 1, 256, True, None, 30.0),   # MQA + softcap, D > 128
 ])
 def test_flash_attention_matches_ref(dtype, B, S, T, H, K, D, causal,
                                      window, softcap):
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    """Output and dq/dk/dv against jax.vjp of the oracle."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(ks[0], (B, S, H, D), dtype)
     k = jax.random.normal(ks[1], (B, T, K, D), dtype)
     v = jax.random.normal(ks[2], (B, T, K, D), dtype)
+    g = jax.random.normal(ks[3], (B, S, H, D), dtype)
     qp = jnp.arange(T - S, T, dtype=jnp.int32)
     kp = jnp.arange(T, dtype=jnp.int32)
-    out = flash_attention(q, k, v, qp, kp, window=window, softcap=softcap,
-                          causal=causal, interpret=True)
-    ref = attention_ref(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                        v.transpose(0, 2, 1, 3), qp, kp, scale=D ** -0.5,
-                        causal=causal, window=window,
-                        softcap=softcap).transpose(0, 2, 1, 3)
+    t = (0, 2, 1, 3)
+    out, vjp = jax.vjp(
+        lambda q, k, v: flash_attention(q, k, v, qp, kp, window=window,
+                                        softcap=softcap, causal=causal,
+                                        interpret=True), q, k, v)
+    ref, ref_vjp = jax.vjp(
+        lambda q, k, v: attention_ref(
+            q.transpose(t), k.transpose(t), v.transpose(t), qp, kp,
+            scale=D ** -0.5, causal=causal, window=window,
+            softcap=softcap).transpose(t), q, k, v)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol)
+    # gradients: bf16 results carry two ulps of their own magnitude
+    for name, a, b in zip(("dq", "dk", "dv"), vjp(g), ref_vjp(g)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), atol=tol,
+                                   rtol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_rows_that_see_no_key(dtype):
+    """Queries at negative positions see no key: zeros out, no gradient.
+    Their first q block skips every pair; the second straddles key block
+    0 with such rows in it.  The other rows match the oracle."""
+    S, H, K, D, blind = 640, 2, 1, 64, 200
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(ks[0], (1, S, H, D), dtype)
+    k = jax.random.normal(ks[1], (1, S, K, D), dtype)
+    v = jax.random.normal(ks[2], (1, S, K, D), dtype)
+    g = jax.random.normal(ks[3], (1, S, H, D), dtype).at[:, :blind].set(0)
+    qp = jnp.arange(S, dtype=jnp.int32) - blind
+    kp = jnp.arange(S, dtype=jnp.int32)
+    t = (0, 2, 1, 3)
+    out, vjp = jax.vjp(
+        lambda q, k, v: flash_attention(q, k, v, qp, kp, interpret=True),
+        q, k, v)
+    ref, ref_vjp = jax.vjp(
+        lambda q, k, v: attention_ref(
+            q.transpose(t), k.transpose(t), v.transpose(t), qp, kp,
+            scale=D ** -0.5).transpose(t), q, k, v)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    out = np.asarray(out, np.float32)
+    assert not out[:, :blind].any()
+    np.testing.assert_allclose(out[:, blind:],
+                               np.asarray(ref, np.float32)[:, blind:],
+                               atol=tol)
+    # g is zero on the blind rows, so the oracle's gradients take nothing
+    # from its average over their masked keys
+    for name, a, b in zip(("dq", "dk", "dv"), vjp(g), ref_vjp(g)):
+        a = np.asarray(a, np.float32)
+        assert np.isfinite(a).all(), name
+        np.testing.assert_allclose(a, np.asarray(b, np.float32), atol=tol,
+                                   rtol=tol, err_msg=name)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

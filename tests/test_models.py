@@ -13,7 +13,8 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS, reduced
-from repro.models import build_model
+from repro.models import attention, build_model
+from repro.models.attention import PALLAS_MIN_T
 from repro.optim.optimizers import OptimizerConfig
 from repro.runtime.train import TrainConfig, make_train_step
 
@@ -162,6 +163,67 @@ def test_chunked_equals_naive_attention():
     # logit in 16384 off by 0.0547 = 7 ulps at [2, 4)).
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
                                atol=0.125, rtol=1e-2)
+
+
+@pytest.mark.parametrize("backend,T,pos_ndim,want", [
+    ("tpu", PALLAS_MIN_T, 1, "pallas"),
+    ("tpu", 4 * PALLAS_MIN_T, 1, "pallas"),
+    ("tpu", PALLAS_MIN_T - 1, 1, "naive"),
+    ("tpu", PALLAS_MIN_T, 2, "naive"),      # per-sequence positions
+    ("cpu", PALLAS_MIN_T, 1, "naive"),
+    ("cpu", 4096, 1, "chunked"),
+])
+def test_auto_attention_routing(backend, T, pos_ndim, want, monkeypatch):
+    """impl="auto" runs the flash kernels on the TPU from PALLAS_MIN_T
+    keys up, and keeps naive/chunked elsewhere (the CPU: tier-1)."""
+    from repro.kernels.flash_attention import ops
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    calls = []
+
+    def stub(q, k, v, *args, **kwargs):
+        calls.append(k.shape[1])
+        return q
+
+    monkeypatch.setattr(ops, "flash_attention", stub)
+    pos = jnp.zeros((1,) * (pos_ndim - 1) + (T,), jnp.int32)
+    assert attention.auto_impl(T, pos) == want
+    q = jax.ShapeDtypeStruct((1, T, 2, 8), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, T, 1, 8), jnp.bfloat16)
+    jax.eval_shape(lambda q, k: attention.sdpa(q, k, k, pos, pos, None,
+                                               None, 0.125), q, kv)
+    assert calls == ([T] if want == "pallas" else [])
+
+
+@pytest.mark.parametrize("model,want", [(1, "pallas"), (4, "naive")])
+def test_auto_attention_routing_on_a_mesh(model, want, monkeypatch):
+    """A call partitioned over a multi-device mesh keeps the plain path:
+    Mosaic kernels have no partitioning rule."""
+    from jax.sharding import AbstractMesh, AxisType, use_abstract_mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = AbstractMesh((1, model), ("data", "model"),
+                        axis_types=(AxisType.Auto,) * 2)
+    with use_abstract_mesh(mesh):
+        got = attention.auto_impl(PALLAS_MIN_T,
+                                  jnp.zeros((PALLAS_MIN_T,), jnp.int32))
+    assert got == want
+
+
+def test_decode_never_takes_the_kernels(monkeypatch):
+    """One-query decode keeps sdpa_naive, however long the cache."""
+    from repro.kernels.flash_attention import ops
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("decode reached the flash kernels")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ops, "flash_attention", refuse)
+    cfg = reduced(ARCHS["smollm-360m"])
+    params = attention.attention_init(jax.random.PRNGKey(0), cfg)
+    cache = attention.init_kv_cache(cfg, 2, 2 * PALLAS_MIN_T)
+    x = jnp.ones((2, 1, cfg.d_model), jnp.bfloat16)
+    y, _ = jax.eval_shape(lambda x: attention.decode_attention(
+        params, x, cache, cfg, jnp.array([3, PALLAS_MIN_T + 5])), x)
+    assert y.shape == x.shape
 
 
 def test_param_count_analytic_matches_tree():

@@ -13,6 +13,7 @@ imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +27,7 @@ from repro.kernels.ssd.ops import ssd
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -40,8 +41,13 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e[0])
 
 
 def _spec(sharding, shape, dtype):
@@ -64,6 +70,96 @@ def test_flash_attention_compiles_smollm_train(one_chip):
         lambda q, k, v, qp, kp: flash_attention(q, k, v, qp, kp,
                                                 interpret=False),
         q, kv, kv, pos, pos)
+
+
+def test_flash_attention_grad_compiles_smollm_train(one_chip):
+    """jax.grad through the kernels at smollm-360m's training shape: the
+    forward and both backward kernels, and no S x S score buffer."""
+    cfg = ARCHS["smollm-360m"]
+    B, S = 8, 2048
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    q = _spec(one_chip, (B, S, cfg.n_heads, cfg.head_dim), bf16)
+    kv = _spec(one_chip, (B, S, cfg.n_kv_heads, cfg.head_dim), bf16)
+    pos = _spec(one_chip, (S,), i32)
+
+    def loss(q, k, v, qp, kp):
+        out = flash_attention(q, k, v, qp, kp, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv, pos, pos).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert any(re.search(rf"%{name}(\.\d+)? = ", line)
+                   for line in calls)
+    assert f"{S},{S}]" not in text
+
+
+# every config with attention, at blocks of 512 (the largest folded tile:
+# G query heads x 512 rows), with its window and softcap; seamless's
+# cross-attention is non-causal with fewer keys than queries
+ATTN_CASES = [(a, False) for a, c in ARCHS.items() if c.family != "ssm"] + [
+    ("seamless-m4t-large-v2", True)]
+
+
+@pytest.mark.parametrize("arch,cross", ATTN_CASES,
+                         ids=[a + ("-cross" if x else "")
+                              for a, x in ATTN_CASES])
+def test_flash_attention_grad_compiles_each_config(one_chip, arch, cross):
+    cfg = ARCHS[arch]
+    S = 1024
+    T = S // 2 if cross else S
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    q = _spec(one_chip, (1, S, cfg.n_heads, cfg.head_dim), bf16)
+    kv = _spec(one_chip, (1, T, cfg.n_kv_heads, cfg.head_dim), bf16)
+
+    def loss(q, k, v, qp, kp):
+        out = flash_attention(q, k, v, qp, kp, window=cfg.sliding_window,
+                              softcap=cfg.attn_softcap, causal=not cross,
+                              interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv, _spec(one_chip, (S,), i32),
+        _spec(one_chip, (T,), i32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 3
+
+
+@pytest.mark.parametrize("devices,kernels", [(1, True), (4, False)])
+def test_train_step_auto_attention_on_a_mesh(v5e, devices, kernels,
+                                             monkeypatch):
+    """A train step through sdpa(impl="auto") on the TPU at
+    PALLAS_MIN_T keys: the flash kernels on a one-device mesh; on the
+    host's (1, 4) mesh, which Mosaic kernels cannot be partitioned over,
+    the plain path, and the step compiles."""
+    from repro.data.pipeline import DataConfig, batch_for_model
+    from repro.configs import reduced
+    from repro.launch.mesh import make_auto_mesh
+    from repro.models.attention import PALLAS_MIN_T
+    from repro.runtime.parallel import ParallelContext, parallel_context
+    from repro.runtime.sharding import state_shardings
+    from repro.runtime.train import TrainConfig, make_train_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = reduced(ARCHS["smollm-360m"])
+    step_fn, init_fn = make_train_step(cfg, TrainConfig())
+    batch = batch_for_model(cfg, DataConfig(seq_len=PALLAS_MIN_T,
+                                            global_batch=4,
+                                            vocab_size=cfg.vocab_size), 0)
+    mesh = make_auto_mesh((1, devices), ("data", "model"),
+                          devices=v5e[:devices])
+    with jax.set_mesh(mesh), parallel_context(ParallelContext()):
+        abstract = jax.eval_shape(lambda: init_fn(jax.random.PRNGKey(0)))
+        st_sh = state_shardings(mesh, abstract, "adamw")
+        state = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            abstract, st_sh)
+        rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+        batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=rep)
+                 for k, v in batch.items()}
+        text = jax.jit(step_fn).lower(state, batch).compile().as_text()
+    assert ("tpu_custom_call" in text) == kernels
 
 
 def test_ssd_compiles_mamba2_widths(one_chip):
