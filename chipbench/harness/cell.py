@@ -5,7 +5,7 @@ import dataclasses
 import importlib
 import shutil
 import tempfile
-from typing import Callable, Optional
+from typing import Optional
 
 import numpy as np
 
@@ -25,7 +25,7 @@ class Context:
     t_start: float                  # the process's clock at its start
     trace_dir: Optional[str]
     fault: Optional[str]
-    peak_bytes: Callable[[], int]
+    devices: list                   # the cell's chips, as device.require
 
 
 @dataclasses.dataclass
@@ -65,7 +65,8 @@ def checks(ctx: Context, kind: str, out: dict, detail: dict,
     with float8 matrix products (reference/common.py)."""
     if kind == "train":
         from reference import train as ref
-        args = (ctx.config, ctx.traffic, ctx.seed, ctx.limits["check_steps"])
+        args = (ctx.config, ctx.traffic, ctx.seed, ctx.limits["check_steps"],
+                ctx.devices)
         rows = ctx.limits["reference_rows"]
         detail["reference"] = ref.readings(*args, rows=rows)
         prog = (ref.readings(*args, precision="fp8", rows=rows) if control
@@ -102,7 +103,7 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     trace_dir = tempfile.mkdtemp(prefix="chipbench-") if trace else None
     try:
         ctx = Context(config, traffic, limits, seed, seconds, t_start,
-                      trace_dir, fault, lambda: device.peak_bytes(devices))
+                      trace_dir, fault, devices)
         kind = traffic["kind"]
         out = importlib.import_module("harness." + kind).run(ctx)
         if trace:

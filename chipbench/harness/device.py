@@ -24,6 +24,15 @@ def require(n_chips: int, allow_cpu: bool = False) -> list:
     return devices[:n_chips]
 
 
+def mesh(devices):
+    """The (data=1, model=n) mesh over exactly the cell's n devices, as the
+    program's launcher lays one over a host's (`--mesh host`); on one
+    device, the launcher's one-device mesh."""
+    from repro.launch.mesh import make_auto_mesh
+    return make_auto_mesh((1, len(devices)), ("data", "model"),
+                          devices=devices)
+
+
 def peak_bytes(devices) -> int:
     """Peak bytes in use on the fullest chip since the process started."""
     return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)

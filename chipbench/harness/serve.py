@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import faults, flops, weights
+from . import device, faults, flops, weights
 from . import traffic as gen
 from .tracing import Window, settle, span
 
@@ -88,7 +88,6 @@ class Loop:
 
 def run(ctx) -> dict:
     from repro.configs.base import ModelConfig
-    from repro.launch.mesh import make_one_device_mesh
     from repro.models import build_model
     from repro.runtime.parallel import ParallelContext, parallel_context
     from repro.runtime.serve import ServeConfig, make_serve_fns
@@ -105,7 +104,7 @@ def run(ctx) -> dict:
     kd = weights.key_data(ctx.seed)
     loop = Loop(tr, m["vocab_size"], ctx.seed)
 
-    mesh = make_one_device_mesh()
+    mesh = device.mesh(ctx.devices)
     with jax.set_mesh(mesh), parallel_context(ParallelContext()):
         weights.check_layout(jax.eval_shape(make_params, kd), jax.eval_shape(
             build_model(cfg).init, jax.random.PRNGKey(0)))
@@ -149,7 +148,7 @@ def run(ctx) -> dict:
                 break
         elapsed = ends[-1] - ends[0]
         window.stop()
-        memory_peak = ctx.peak_bytes()
+        memory_peak = device.peak_bytes(ctx.devices)
         del params, cache, step
 
     return {

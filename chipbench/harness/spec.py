@@ -5,9 +5,14 @@
     <bench>/limits/<workload>.json    limits of the comparison that decides
                                       `correct`
     <bench>/metrics/<metric>.py       reader of one per-layer metric
+    <bench>/reference/<reference>.py  one family, as the configuration's
+                                      `reference` names it: its plain
+                                      forward pass, the weights made from
+                                      the seed, its FLOPs
 
-A later cell, mix or metric is added as new files and entries; nothing
-here names one.
+A later cell, mix, metric or family is added as new files and entries;
+nothing here names one.  A cell's `chips` are the devices its step and
+its reference run over.
 """
 
 import importlib.util

@@ -4,20 +4,28 @@ Set-up makes the state from the seed on the device, compiles the step
 once, and runs the first `check_steps` steps through the same call and
 feed as the window; their losses, the first gradient (read back from the
 optimizer's first moment) and the parameters' change over them are what
-the reference is compared with.  The window then runs steps until
-`--seconds` have passed; each step builds its batch on the host, calls
-the step and waits for its metrics with `device_get`, as the program's
-launcher does.
+the reference is compared with.  Each step builds its batch on the host,
+calls the step and fetches its metrics with `device_get`.  The window
+keeps `AHEAD_S` seconds of steps (a fifth of the window at most, timed
+by the last checked step) dispatched ahead of the one whose metrics it
+waits for, so that the chip runs on while the host stands still.  Once
+`--seconds` have passed it sends nothing more, waits for every step it
+sent, and reads the clock after that wait: all of those steps count,
+over all of that time.
 """
 
+import collections
+import math
 import time
 
 import jax
 import jax.numpy as jnp
 
-from . import faults, flops, weights
+from . import device, faults, flops, weights
 from . import traffic as gen
 from .tracing import Window, settle, span
+
+AHEAD_S = 6.0
 
 
 def _norms(tree):
@@ -32,7 +40,6 @@ def _by_path(tree) -> dict:
 
 def run(ctx) -> dict:
     from repro.configs.base import ModelConfig
-    from repro.launch.mesh import make_one_device_mesh
     from repro.models import build_model
     from repro.optim.optimizers import OptimizerConfig, build_optimizer
     from repro.runtime.parallel import ParallelContext, parallel_context
@@ -61,7 +68,7 @@ def run(ctx) -> dict:
         b = gen.train_batch(tr, m["vocab_size"], ctx.seed, s)
         return {k: jnp.asarray(v) for k, v in b.items()}
 
-    mesh = make_one_device_mesh()
+    mesh = device.mesh(ctx.devices)
     with jax.set_mesh(mesh), parallel_context(ParallelContext()):
         abstract = jax.eval_shape(make_state, kd)
         weights.check_layout(abstract["params"], jax.eval_shape(
@@ -72,18 +79,22 @@ def run(ctx) -> dict:
                        out_shardings=(sh, None)).lower(state,
                                                        batch(0)).compile()
 
-        def one(state, s):
+        def send(state, s):
             with span("bench.batch"):
                 b = batch(s)
             with span("bench.dispatch"):
-                state, met = step(state, b)
+                return step(state, b)
+
+        def fetch(met):
             with span("bench.fetch"):
-                met = jax.device_get(met)
-            return state, met
+                return jax.device_get(met)
 
         losses, grad = [], None
         for s in range(n_check):
-            state, met = one(state, s)
+            t = time.perf_counter()
+            state, met = send(state, s)
+            met = fetch(met)
+            step_s = time.perf_counter() - t
             losses.append(float(met["loss"]))
             if s == 0:
                 grad = {k: v / (1.0 - o["b1"]) for k, v in _by_path(
@@ -99,18 +110,28 @@ def run(ctx) -> dict:
         settle()
         setup_s = time.perf_counter() - ctx.t_start
 
+        ahead = max(1, math.ceil(min(AHEAD_S, ctx.seconds / 5) / step_s))
         window = Window(ctx.trace_dir, tr["trace_seconds"])
+        sent, s = collections.deque(), n_check
         ends = [time.perf_counter()]
         window.start()
         while True:
-            state, met = one(state, n_check + len(ends) - 1)
+            while len(sent) < ahead:
+                state, met = send(state, s)
+                sent.append(met)
+                s += 1
+            fetch(sent.popleft())
             ends.append(time.perf_counter())
             window.step_done()
             if ends[-1] - ends[0] >= ctx.seconds:
                 break
+        while sent:
+            fetch(sent.popleft())
+            ends.append(time.perf_counter())
+            window.step_done()
         steps, elapsed = len(ends) - 1, ends[-1] - ends[0]
         window.stop()
-        memory_peak = ctx.peak_bytes()
+        memory_peak = device.peak_bytes(ctx.devices)
         del state, step
 
     tokens = tr["batch"] * tr["seq_len"]

@@ -1,4 +1,4 @@
-"""Pieces shared by the plain references.
+"""Pieces shared by the plain references and their weight makers.
 
 `quantizer(name)` gives the rounding applied to both operands of every
 matrix product: none for "f32", and rounding to float8 e4m3's 3 mantissa
@@ -12,8 +12,17 @@ Rounding is `lax.reduce_precision`: XLA may drop a round trip of casts
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 F32 = jnp.float32
+BF16 = jnp.bfloat16
+
+
+def mat(key, shape, fan_in, gain=1.0):
+    """A weight matrix made from `key`: normal / sqrt(fan_in) times gain,
+    held in bfloat16."""
+    return (jax.random.normal(key, shape, jnp.float32)
+            * (gain / np.sqrt(fan_in))).astype(BF16)
 
 
 def quantizer(name: str):

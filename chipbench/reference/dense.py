@@ -8,12 +8,57 @@ is of the arithmetic, and the configuration file names the departure:
 the embedding is scaled by sqrt(d_model).  RoPE rotates adjacent pairs of
 dimensions, as Meta's original Llama code does (Hugging Face's
 rotate-half form is the same up to a fixed permutation of q/k columns).
+
+Beside the forward pass: `weights`, the tree the benchmark makes from the
+seed in the program's layout, and `forward_flops`, the forward pass's
+operations in closed form (conventions in harness/flops.py).
 """
 
 import jax
 import jax.numpy as jnp
 
-from .common import matmul, rmsnorm
+from .common import BF16, mat, matmul, rmsnorm
+
+
+def weights(m: dict, key, init: dict) -> dict:
+    """`init["out_gain"]` scales the blocks' output projections (wo,
+    w_down), which write into the residual stream; see the configuration
+    file for why."""
+    L, d, H, K, hd, F, V = (m["n_layers"], m["d_model"], m["n_heads"],
+                            m["n_kv_heads"], m["head_dim"], m["d_ff"],
+                            m["vocab_size"])
+    g = init.get("out_gain", 1.0)
+    ks = jax.random.split(key, 8)
+    return {
+        "embed": {"table": mat(ks[0], (V, d), d)},
+        "final_norm": {"scale": jnp.ones((d,), BF16)},
+        "units": {
+            "b0": {"norm": {"scale": jnp.ones((L, d), BF16)},
+                   "attn": {"wq": mat(ks[1], (L, d, H * hd), d),
+                            "wk": mat(ks[2], (L, d, K * hd), d),
+                            "wv": mat(ks[3], (L, d, K * hd), d),
+                            "wo": mat(ks[4], (L, H * hd, d), H * hd, g)}},
+            "b1": {"norm": {"scale": jnp.ones((L, d), BF16)},
+                   "mlp": {"w_gate": mat(ks[5], (L, d, F), d),
+                           "w_up": mat(ks[6], (L, d, F), d),
+                           "w_down": mat(ks[7], (L, F, d), F, g)}},
+        },
+    }
+
+
+def matmul_params(m: dict) -> int:
+    """Weights that take part in a matrix product for every token,
+    including the tied unembedding."""
+    d, H, K, hd, F, V = (m["d_model"], m["n_heads"], m["n_kv_heads"],
+                         m["head_dim"], m["d_ff"], m["vocab_size"])
+    per_layer = d * H * hd + 2 * d * K * hd + H * hd * d + 3 * d * F
+    return m["n_layers"] * per_layer + V * d
+
+
+def forward_flops(m: dict, batch: int, seq: int) -> int:
+    tokens = batch * seq
+    attn = 2 * batch * seq * seq * m["n_heads"] * m["head_dim"]  # causal
+    return 2 * tokens * matmul_params(m) + m["n_layers"] * attn
 
 
 def forward(p, tokens, m, q):

@@ -7,14 +7,69 @@ The recurrence is the plain one, h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T,
 y_t = h_t C_t, not the chunked dual form the program uses.  As in the
 program, the embedding is scaled by sqrt(d_model) (a departure from the
 published model that the configuration file names).
+
+Beside the forward pass: `weights`, the tree the benchmark makes from the
+seed in the program's layout, and `forward_flops`, the forward pass's
+operations in closed form (conventions in harness/flops.py).
 """
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from .common import matmul, rmsnorm
+from .common import BF16, mat, matmul, rmsnorm
 
 INNER = 64          # tokens per rematerialised block of the recurrence
+
+
+def weights(m: dict, key, init: dict) -> dict:
+    """Mamba2's published initialisation for A, dt and D (arXiv:2405.21060,
+    reference code): A ~ U[1, 16], dt log-uniform in [1e-3, 1e-1] held as
+    the inverse softplus in dt_bias, D = 1."""
+    L, d, V, N, K = (m["n_layers"], m["d_model"], m["vocab_size"],
+                     m["ssm_state"], m["d_conv"])
+    di = m["expand"] * d
+    H = di // m["ssm_head_dim"]
+    conv_dim = di + 2 * N
+    ks = jax.random.split(key, 6)
+    dt = jnp.exp(jax.random.uniform(ks[4], (L, H), jnp.float32,
+                                    np.log(1e-3), np.log(1e-1)))
+    dt = jnp.maximum(dt, 1e-4)
+    return {
+        "embed": {"table": mat(ks[0], (V, d), d)},
+        "final_norm": {"scale": jnp.ones((d,), BF16)},
+        "units": {"b0": {
+            "norm": {"scale": jnp.ones((L, d), BF16)},
+            "mamba": {
+                "in_proj": mat(ks[1], (L, d, 2 * di + 2 * N + H), d),
+                "conv_w": mat(ks[2], (L, K, conv_dim), K),
+                "conv_b": jnp.zeros((L, conv_dim), BF16),
+                "A_log": jnp.log(jax.random.uniform(ks[3], (L, H),
+                                                    jnp.float32, 1.0, 16.0)),
+                "D": jnp.ones((L, H), jnp.float32),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "gate_norm": {"scale": jnp.ones((L, di), BF16)},
+                "out_proj": mat(ks[5], (L, di, d), di),
+            }}},
+    }
+
+
+def dims(m: dict):
+    di = m["expand"] * m["d_model"]
+    return di, di // m["ssm_head_dim"], m["ssm_head_dim"], m["ssm_state"]
+
+
+def forward_flops(m: dict, batch: int, seq: int) -> int:
+    d, N, K, V, Q = (m["d_model"], m["ssm_state"], m["d_conv"],
+                     m["vocab_size"], m["ssm_chunk"])
+    di, H, P, _ = dims(m)
+    tokens = batch * seq
+    proj = 2 * d * (2 * di + 2 * N + H) + 2 * di * d
+    conv = 2 * K * (di + 2 * N)
+    # per token in a chunk of Q: C.B over the causal half, the gated
+    # product with x over the causal half, the chunk state and its output
+    ssd = Q * N + Q * H * P + 2 * H * P * N + 2 * H * P * N
+    return tokens * (m["n_layers"] * (proj + conv + ssd) + 2 * V * d)
 
 
 def recurrence(x, dt, A, Bm, Cm):

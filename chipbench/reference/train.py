@@ -7,6 +7,11 @@ on every stored tensor of rank 2 or more), moments in float32, and each
 parameter stored back in the type it is held in (bfloat16 for matrices and
 norm scales, float32 where the weights are made in float32).  Gradients are
 computed in float32 at the highest matmul precision, in blocks of rows.
+
+The reference runs over the cell's chips, placed by a rule of its own
+(`shardings`), not the program's: each tensor it keeps split along its
+largest axis that the number of chips divides, the batch on every chip;
+jit and GSPMD place the rest.
 """
 
 import importlib
@@ -14,6 +19,7 @@ import importlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .common import quantizer, store, to_f32
 
@@ -30,6 +36,21 @@ def leaf_norms(tree) -> dict:
     return {jax.tree_util.keystr(k): float(v) for k, v in flat[0]}
 
 
+def shardings(tree, mesh: Mesh):
+    """For each leaf of `tree` (arrays or shapes): split over the mesh's
+    one axis along the leaf's largest axis that the axis's size divides
+    (the first of equals), else held whole on every device."""
+    n = mesh.devices.size
+
+    def one(x):
+        fits = [i for i, k in enumerate(x.shape) if k % n == 0]
+        spec = [None] * len(x.shape)
+        if fits:
+            spec[max(fits, key=lambda i: x.shape[i])] = "x"
+        return NamedSharding(mesh, P(*spec))
+    return jax.tree.map(one, tree)
+
+
 def lr_at(o: dict, step: int) -> float:
     warm = min((step + 1.0) / max(o["warmup_steps"], 1), 1.0)
     prog = min(max((step - o["warmup_steps"])
@@ -37,7 +58,8 @@ def lr_at(o: dict, step: int) -> float:
     return o["lr"] * warm * 0.5 * (1.0 + np.cos(np.pi * prog))
 
 
-def make_grad_fn(config: dict, z_weight: float, rows: int, precision: str):
+def make_grad_fn(config: dict, z_weight: float, rows: int, precision: str,
+                 out_shardings):
     fwd, m, q = family(config).forward, config["model"], quantizer(precision)
 
     def block_loss(p, tok, lab):
@@ -62,7 +84,7 @@ def make_grad_fn(config: dict, z_weight: float, rows: int, precision: str):
         n = B * S
         return v / n, jax.tree.map(lambda x: x / n, gr)
 
-    return jax.jit(loss_and_grads)
+    return jax.jit(loss_and_grads, out_shardings=out_shardings)
 
 
 def adamw(o: dict, dtypes, p, g, mu, nu, lr, bc1, bc2):
@@ -84,20 +106,27 @@ def adamw(o: dict, dtypes, p, g, mu, nu, lr, bc1, bc2):
 
 
 def readings(config: dict, traffic: dict, seed: int, n_steps: int,
-             precision: str = "f32", rows: int = 2, half_batch=False) -> dict:
+             devices: list, precision: str = "f32", rows: int = 2,
+             half_batch=False) -> dict:
     """Losses of the first n_steps, per-leaf norms of the first (clipped)
-    gradient and of the parameters' change over the n_steps."""
+    gradient and of the parameters' change over the n_steps, computed over
+    `devices`."""
     from harness import traffic as gen, weights
     o = traffic["optimizer"]
     V = config["model"]["vocab_size"]
+    mesh = Mesh(np.array(devices), ("x",))
+    whole = NamedSharding(mesh, P())
+    make, kd = weights.maker(config), weights.key_data(seed)
+    sh = shardings(jax.eval_shape(make, kd), mesh)
     with jax.default_matmul_precision("highest"):
-        made = jax.jit(weights.maker(config))(weights.key_data(seed))
+        made = jax.jit(make, out_shardings=sh)(kd)
         dtypes = jax.tree.map(lambda x: x.dtype, made)
         p0 = to_f32(made)
         del made
         grads = make_grad_fn(config, traffic["z_loss_weight"], rows,
-                             precision)
-        step = jax.jit(lambda *a: adamw(o, dtypes, *a))
+                             precision, (whole, sh))
+        step = jax.jit(lambda *a: adamw(o, dtypes, *a),
+                       out_shardings=(sh,) * 4)
         p = p0
         mu = nu = jax.tree.map(jnp.zeros_like, p0)
         losses, first = [], None
@@ -106,7 +135,8 @@ def readings(config: dict, traffic: dict, seed: int, n_steps: int,
             tok, lab = b["tokens"], b["labels"]
             if half_batch:
                 tok, lab = tok[:len(tok) // 2], lab[:len(lab) // 2]
-            loss, g = grads(p, jnp.asarray(tok), jnp.asarray(lab))
+            loss, g = grads(p, jax.device_put(tok, whole),
+                            jax.device_put(lab, whole))
             p, g, mu, nu = step(p, g, mu, nu, lr_at(o, s),
                                 1.0 - o["b1"] ** (s + 1.0),
                                 1.0 - o["b2"] ** (s + 1.0))

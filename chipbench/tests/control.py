@@ -26,12 +26,13 @@ ROOT = os.path.dirname(BENCH)
 
 def readings(root, workload, seeds, control_seeds, seconds,
              allow_cpu=False, keep_trace=None):
-    from harness import cell, correct, spec
+    from harness import cell, correct, device, spec
     from reference import train as rtrain
     bench = spec.Bench(root)
     w = bench.workload(workload)
     config, traffic = bench.config(w["config"]), bench.traffic(w["traffic"])
     limits = bench.limits(workload)
+    devices = device.require(w["chips"], allow_cpu)
     rows = []
     for i, seed in enumerate(seeds):
         detail, t = {}, time.perf_counter()
@@ -47,7 +48,8 @@ def readings(root, workload, seeds, control_seeds, seconds,
             t = time.perf_counter()
             if traffic["kind"] == "train":
                 ref = detail["reference"]
-                args = (config, traffic, seed, limits["check_steps"])
+                args = (config, traffic, seed, limits["check_steps"],
+                        devices)
                 rows_ = limits["reference_rows"]
                 row["control"] = correct.train(
                     rtrain.readings(*args, precision="fp8", rows=rows_), ref)

@@ -71,7 +71,7 @@ def layers(root, workload, seed, seconds, kept, trace_seconds=None,
     try:
         ctx = cell.Context(config, traffic, bench.limits(workload), seed,
                            seconds, time.perf_counter(), trace_dir, None,
-                           lambda: device.peak_bytes(devices))
+                           devices)
         with compile_work() as work:
             out = train.run(ctx)
         t = time.perf_counter()

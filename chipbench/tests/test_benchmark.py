@@ -64,6 +64,17 @@ def test_every_cell_reports_enough(spec):
         assert layer and all(m["moves"] in e2e for m in layer)
 
 
+def test_every_reference_is_a_whole_family(spec):
+    """A family is one module, reference/<config["reference"]>.py: its
+    forward pass, its weights and its FLOPs."""
+    from reference.train import family
+    for c in spec["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            mod = family(json.load(f))
+        for fn in ("forward", "weights", "forward_flops"):
+            assert callable(getattr(mod, fn, None)), (c["name"], fn)
+
+
 def test_exits_without_a_chip():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run([sys.executable, os.path.join(BENCH, "run.py"),

@@ -53,3 +53,19 @@ def test_traced_run_reports_per_layer_only(root):
     assert "train_tokens_per_s" not in r["metrics"]
     assert set(r["device"]) >= {"busy_s", "window_s"}
     assert set(r["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def test_window_counts_every_step_it_sent(root, monkeypatch):
+    """Steps are dispatched ahead in the window; when it closes, every
+    step sent has been waited for and counted."""
+    from harness import train
+    made = []
+    real = train.gen.train_batch
+    monkeypatch.setattr(train.gen, "train_batch",
+                        lambda *a: made.append(a[-1]) or real(*a))
+    r = _run(root, "tiny-dense.train.tiny")
+    assert r["correct"], r["checks"]
+    window = made[made.index(2) + 1:]      # after the checked steps 0-2
+    window = window[:window.index(0)]      # before the reference's batches
+    assert window == list(range(3, 3 + len(window)))
+    assert r["attempted"] == len(window) == r["step_s"]["n"]

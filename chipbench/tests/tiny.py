@@ -66,31 +66,75 @@ def _dump(obj, *path):
         json.dump(obj, f)
 
 
+def _load(*path):
+    with open(os.path.join(*path)) as f:
+        return json.load(f)
+
+
+def _add_config(spec: dict, bench: str, cfg: dict) -> None:
+    _dump(cfg, bench, "configs", cfg["name"] + ".json")
+    spec["configs"].append({
+        "name": cfg["name"], "source": "https://example.org/tiny",
+        "file": f"chipbench/configs/{cfg['name']}.json",
+        "reduced": [], "why": "tiny"})
+
+
+def _add_cell(spec: dict, bench: str, name: str, cfg: str, traffic: str,
+              limits: dict) -> None:
+    _dump(limits, bench, "limits", name + ".json")
+    spec["workloads"].append({"name": name, "config": cfg,
+                              "traffic": traffic, "chips": 1,
+                              "why": "tiny"})
+    if ".train." in name:
+        for m in spec["end_to_end"] + spec["per_layer"]:
+            if any(".train." in w for w in m.get("workloads", [])):
+                m["workloads"].append(name)
+
+
+# a family added as one new module: the dense reference under another
+# name, with a configuration of its own that changes one width
+NEW_FAMILY = "decoder"
+TINY_NEW = dict(TINY_DENSE, name="tiny-new", reference=NEW_FAMILY,
+                model=dict(TINY_DENSE["model"], name="tiny-new", d_ff=96))
+
+
+def add_family(root: str) -> str:
+    """Add to the tree at root, as new files and entries alone, the module
+    reference/<NEW_FAMILY>.py, the configuration TINY_NEW and its train
+    cell; returns the cell's name."""
+    bench = os.path.join(root, "chipbench")
+    shutil.copy(os.path.join(BENCH, "reference", "dense.py"),
+                os.path.join(bench, "reference", NEW_FAMILY + ".py"))
+    spec = _load(root, "BENCHMARK.json")
+    _add_config(spec, bench, TINY_NEW)
+    name = TINY_NEW["name"] + ".train.tiny"
+    _add_cell(spec, bench, name, TINY_NEW["name"], "train.tiny",
+              TRAIN_LIMITS)
+    _dump(spec, root, "BENCHMARK.json")
+    return name
+
+
+def set_chips(root: str, workload: str, chips: int) -> None:
+    """The cell `workload` of the tree at root asks for `chips` chips."""
+    spec = _load(root, "BENCHMARK.json")
+    next(w for w in spec["workloads"] if w["name"] == workload)[
+        "chips"] = chips
+    _dump(spec, root, "BENCHMARK.json")
+
+
 def make(tmp: str) -> str:
     """A copy of the benchmark at tmp with the tiny cells added; returns
     the root to run from."""
     shutil.copytree(BENCH, os.path.join(tmp, "chipbench"),
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
     bench = os.path.join(tmp, "chipbench")
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        spec = json.load(f)
+    spec = _load(ROOT, "BENCHMARK.json")
     for cfg in (TINY_DENSE, TINY_SSM):
-        _dump(cfg, bench, "configs", cfg["name"] + ".json")
-        spec["configs"].append({
-            "name": cfg["name"], "source": "https://example.org/tiny",
-            "file": f"chipbench/configs/{cfg['name']}.json",
-            "reduced": [], "why": "tiny"})
+        _add_config(spec, bench, cfg)
     _dump(TRAIN, bench, "traffic", "train.tiny.json")
     _dump(SERVE, bench, "traffic", "serve.tiny.json")
     for name, (cfg, traffic, limits) in CELLS.items():
-        _dump(limits, bench, "limits", name + ".json")
-        spec["workloads"].append({"name": name, "config": cfg,
-                                  "traffic": traffic, "chips": 1,
-                                  "why": "tiny"})
-    train = [n for n in CELLS if ".train." in n]
-    for m in spec["end_to_end"] + spec["per_layer"]:
-        if any(".train." in w for w in m.get("workloads", [])):
-            m["workloads"] += train
+        _add_cell(spec, bench, name, cfg, traffic, limits)
     # no serving cell is committed yet: its metrics come with it, as
     # entries of their own
     serve = ["tiny-dense.serve.tiny"]
