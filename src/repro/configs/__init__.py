@@ -1,7 +1,7 @@
 """Architecture registry: every assigned arch + the paper's platform."""
 
 from .base import ModelConfig, ShapeConfig, SHAPES, BlockSpec
-from .zamba2_2p7b import CONFIG as zamba2_2p7b
+from .zamba2_7b import CONFIG as zamba2_7b
 from .chatglm3_6b import CONFIG as chatglm3_6b
 from .gemma2_2b import CONFIG as gemma2_2b
 from .smollm_360m import CONFIG as smollm_360m
@@ -13,7 +13,7 @@ from .pixtral_12b import CONFIG as pixtral_12b
 from .seamless_m4t_v2 import CONFIG as seamless_m4t_v2
 
 ARCHS = {
-    "zamba2-2.7b": zamba2_2p7b,
+    "zamba2-7b": zamba2_7b,
     "chatglm3-6b": chatglm3_6b,
     "gemma2-2b": gemma2_2b,
     "smollm-360m": smollm_360m,
@@ -36,8 +36,7 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
     layers_per_unit = max(1, sum(1 for b in cfg.unit
                                  if b.kind in ("attn", "mamba")))
     small = dict(
-        n_layers=2 * layers_per_unit if cfg.shared_attn_every == 0
-        else 2 * cfg.shared_attn_every,
+        n_layers=2 * layers_per_unit,
         d_model=64,
         n_heads=4,
         n_kv_heads=min(cfg.n_kv_heads, 2),
@@ -55,6 +54,15 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         n_encoder_layers=2 if cfg.n_encoder_layers else 0,
         unit=(),  # rebuilt for the reduced dims
     )
+    if cfg.attn_input_dim:        # the same multiple of d_model
+        small["attn_input_dim"] = cfg.attn_input_dim * 64 // cfg.d_model
+    if cfg.softmax_scale_dim:     # the same fraction of head_dim
+        small["softmax_scale_dim"] = cfg.softmax_scale_dim * 16 // cfg.head_dim
+    if cfg.hybrid_layer_ids:      # every shared block, once, after a mamba
+        k = cfg.num_mem_blocks
+        small.update(n_layers=2 * k,
+                     hybrid_layer_ids=tuple(range(1, 2 * k, 2)),
+                     adapter_rank=min(cfg.adapter_rank, 8))
     small.update(overrides)
     return dataclasses.replace(cfg, **small)
 

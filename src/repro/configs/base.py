@@ -46,10 +46,17 @@ class ModelConfig:
     rope_fraction: float = 1.0            # chatglm 2d-RoPE: 0.5
     qkv_bias: bool = False
     attn_softcap: Optional[float] = None  # gemma2: 50.0
+    # attention's input width (default d_model; zamba2's shared block reads
+    # [x, x0], 2 * d_model) and the width whose -1/2 power scales the
+    # scores (default head_dim; zamba2: head_dim / 2)
+    attn_input_dim: Optional[int] = None
+    softmax_scale_dim: Optional[int] = None
     final_softcap: Optional[float] = None  # gemma2: 30.0
     sliding_window: Optional[int] = None  # mixtral SWA / gemma2 local
     tie_embeddings: bool = False
-    activation: str = "silu"              # silu | geglu | gelu
+    # whether a tied embedding is multiplied by sqrt(d_model) on input
+    scale_tied_embedding: bool = True
+    activation: str = "silu"              # silu | geglu | geglu_erf | gelu
 
     # MoE
     n_experts: int = 0
@@ -62,10 +69,16 @@ class ModelConfig:
     expand: int = 2
     ssm_head_dim: int = 64
     ssm_chunk: int = 256
+    ssm_groups: int = 1                   # B/C groups; head h reads h // (H/G)
 
-    # hybrid (zamba2): one SHARED attention block applied every
-    # `shared_attn_every` layers (weights reused — the Zamba trick)
-    shared_attn_every: int = 0
+    # hybrid (zamba2): before each layer in `hybrid_layer_ids` the k-th
+    # such layer applies shared attention+MLP block k % num_mem_blocks to
+    # [x, x0] (x0 the embedding output), with its own rank-`adapter_rank`
+    # adapter on the MLP's gate/up and its own d x d `linear`, whose
+    # output is added to that layer's Mamba input
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
 
     # encoder-decoder (seamless)
     n_encoder_layers: int = 0
@@ -82,6 +95,13 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        object.__setattr__(self, "hybrid_layer_ids",
+                           tuple(self.hybrid_layer_ids))
+        ids = self.hybrid_layer_ids
+        assert list(ids) == sorted(set(ids)) and all(
+            0 <= i < self.n_layers for i in ids), (self.name, ids)
+        assert not ids or self.num_mem_blocks > 0, self.name
+        assert self.n_ssm_heads % self.ssm_groups == 0, self.name
         if not self.unit:
             object.__setattr__(self, "unit", self.build_unit())
         layers_per_unit = max(
@@ -93,7 +113,7 @@ class ModelConfig:
         if self.family == "ssm":
             return (BlockSpec("mamba"),)
         if self.family == "hybrid":
-            # zamba-style: shared_attn handled outside the unit list
+            # zamba-style: the shared blocks sit outside the unit list
             return (BlockSpec("mamba"),)
         if self.family == "moe":
             blocks = [BlockSpec("attn", window=self.sliding_window,
@@ -120,6 +140,18 @@ class ModelConfig:
         return self.d_inner // self.ssm_head_dim
 
     @property
+    def d_attn_in(self) -> int:
+        return self.attn_input_dim or self.d_model
+
+    @property
+    def attn_scale(self) -> float:
+        return (self.softmax_scale_dim or self.head_dim) ** -0.5
+
+    @property
+    def gated_mlp(self) -> bool:
+        return self.activation in ("silu", "geglu", "geglu_erf")
+
+    @property
     def is_encdec(self) -> bool:
         return self.n_encoder_layers > 0
 
@@ -132,39 +164,49 @@ class ModelConfig:
         tests); used for MODEL_FLOPS = 6*N*D."""
         d, h = self.d_model, self.head_dim
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        per_attn = d * (self.n_heads * h) + 2 * d * (self.n_kv_heads * h) \
-            + (self.n_heads * h) * d
-        if self.qkv_bias:
-            per_attn += (self.n_heads + 2 * self.n_kv_heads) * h
-        act_mult = 3 if self.activation in ("silu", "geglu") else 2
+        per_attn = self._attn_params(d)
+        act_mult = 3 if self.gated_mlp else 2
         per_mlp = act_mult * d * self.d_ff
         per_moe = (self.n_experts * act_mult * d * (self.moe_d_ff or self.d_ff)
                    + d * self.n_experts)
         dssm = self.d_inner
-        g_n = 2 * self.ssm_state  # single B/C group
+        g_n = 2 * self.ssm_groups * self.ssm_state
         per_mamba = (d * (2 * dssm + g_n + self.n_ssm_heads)  # in_proj
-                     + self.d_conv * (dssm + g_n)             # conv
+                     + (self.d_conv + 1) * (dssm + g_n)       # conv, bias
                      + 3 * self.n_ssm_heads                   # A, D, dt_bias
+                     + dssm                                   # gated norm
                      + dssm * d)                              # out_proj
-        total = emb
-        norms = 2 * d
+        total = emb + d                                       # final norm
+        norms = d
         n_dec = self.n_layers
         kinds = {"attn": per_attn + norms, "mlp": per_mlp + norms,
                  "moe": per_moe + norms, "mamba": per_mamba + norms}
         per_unit = sum(kinds[b.kind] for b in self.unit)
         total += self.n_units * per_unit
-        if self.shared_attn_every:
-            total += per_attn + per_mlp + 2 * norms
+        if self.hybrid_layer_ids:
+            d_in = self.d_attn_in
+            per_block = self._attn_params(d_in) + per_mlp + d_in + d
+            per_hybrid = d * d + self.adapter_rank * (d + 2 * self.d_ff)
+            total += (self.num_mem_blocks * per_block
+                      + len(self.hybrid_layer_ids) * per_hybrid)
         if self.is_encdec:
             total += self.n_encoder_layers * (per_attn + per_mlp + 2 * norms)
             total += self.n_layers * (per_attn + norms)  # cross attention
         return int(total)
 
+    def _attn_params(self, d_in: int) -> int:
+        h = self.head_dim
+        n = (d_in * (self.n_heads + 2 * self.n_kv_heads) * h
+             + self.n_heads * h * self.d_model)
+        if self.qkv_bias:
+            n += (self.n_heads + 2 * self.n_kv_heads) * h
+        return n
+
     def active_param_count(self) -> int:
         """Active parameters per token (MoE: top-k of the expert pool)."""
         if not self.n_experts:
             return self.param_count()
-        act_mult = 3 if self.activation in ("silu", "geglu") else 2
+        act_mult = 3 if self.gated_mlp else 2
         per_moe_total = self.n_experts * act_mult * self.d_model * \
             (self.moe_d_ff or self.d_ff)
         per_moe_active = self.experts_per_token * act_mult * self.d_model * \

@@ -6,9 +6,9 @@ and extrapolate  total = full + sum_i multiplier_i * unit_i.
 
 Multipliers per family:
 - uniform decoder (dense/moe/ssm/vlm): (n_units - 1) x unit
-- hybrid (zamba2): the outer scan body holds an inner scan (counted once)
-  plus the shared block => (n_mamba_layers - 1) x mamba_unit and
-  (n_super_units - 1) x shared_block
+- hybrid (zamba2): each run of plain mamba layers between hybrid layers
+  is a scan (its body counted once); the hybrid layers are unrolled and
+  counted in full => (n_plain_layers - n_runs) x mamba_unit
 - enc-dec: (n_enc - 1) x enc_unit + (n_dec - 1) x dec_unit
 """
 
@@ -23,7 +23,6 @@ from repro.configs.base import ModelConfig
 from repro.models import transformer as T
 from repro.models.attention import attention, decode_attention
 from repro.models.layers import mlp, rmsnorm
-from repro.models.ssm import decode_mamba, mamba_block
 
 UnitProgram = Tuple[str, Callable, Tuple, int]  # (name, fn, abstract_args, k)
 
@@ -44,6 +43,15 @@ def _apply_unit(cfg: ModelConfig, unit_params, x, positions, impl):
         x, aux = T._apply_block(unit_params[f"b{j}"], spec, x, cfg,
                                 positions, impl, aux)
     return x, aux
+
+
+def _scanned_extra(cfg: ModelConfig) -> int:
+    """Units the full program's scans hold beyond the one body each counts:
+    a hybrid model scans each run of plain layers and unrolls the rest."""
+    if not cfg.hybrid_layer_ids:
+        return cfg.n_units - 1
+    runs = [stop - start for start, stop, _ in T._runs(cfg) if stop > start]
+    return sum(runs) - len(runs)
 
 
 def _train_wrap(fn):
@@ -105,33 +113,12 @@ def train_unit_programs(cfg: ModelConfig, abstract_state, batch: int,
                     cfg.n_layers - 1))
         return out
 
-    if cfg.shared_attn_every:
-        mamba_u = _abs_slice(params["units"], axes=2)
-        shared = params["shared"]
-
-        def mamba_fn(p, xx):
-            h = rmsnorm(p["norm"], xx, cfg.norm_eps)
-            return xx + mamba_block(p["mamba"], h, cfg, impl=impl), 0.0
-
-        def shared_fn(p, xx):
-            h = rmsnorm(p["norm1"], xx, cfg.norm_eps)
-            xx = xx + attention(p["attn"], h, cfg, positions, impl=impl)
-            h = rmsnorm(p["norm2"], xx, cfg.norm_eps)
-            return xx + mlp(p["mlp"], h, cfg.activation), 0.0
-
-        n_super = cfg.n_layers // cfg.shared_attn_every
-        out.append(("mamba_unit", wrap(mamba_fn), (mamba_u, x),
-                    cfg.n_layers - 1))
-        out.append(("shared_unit", wrap(shared_fn), (shared, x),
-                    n_super - 1))
-        return out
-
     unit = _abs_slice(params["units"])
 
     def unit_fn(p, xx):
         return _apply_unit(cfg, p, xx, positions, impl)
 
-    out.append(("unit", wrap(unit_fn), (unit, x), cfg.n_units - 1))
+    out.append(("unit", wrap(unit_fn), (unit, x), _scanned_extra(cfg)))
     return out
 
 
@@ -162,30 +149,6 @@ def decode_unit_programs(cfg: ModelConfig, abstract_params, abstract_cache,
                     cfg.n_layers - 1))
         return out
 
-    if cfg.shared_attn_every:
-        mamba_u = _abs_slice(params["units"], axes=2)
-        mamba_c = _abs_slice(abstract_cache["units"], axes=2)
-        shared_c = _abs_slice(abstract_cache["shared"])
-
-        def mamba_fn(p, c, xx):
-            h = rmsnorm(p["norm"], xx, cfg.norm_eps)
-            y, c = decode_mamba(p["mamba"], h, c, cfg, pos)
-            return xx + y, c
-
-        def shared_fn(p, c, xx):
-            h = rmsnorm(p["norm1"], xx, cfg.norm_eps)
-            y, c = decode_attention(p["attn"], h, c, cfg, pos)
-            xx = xx + y
-            h = rmsnorm(p["norm2"], xx, cfg.norm_eps)
-            return xx + mlp(p["mlp"], h, cfg.activation), c
-
-        n_super = cfg.n_layers // cfg.shared_attn_every
-        out.append(("mamba_unit", mamba_fn, (mamba_u, mamba_c, x),
-                    cfg.n_layers - 1))
-        out.append(("shared_unit", shared_fn,
-                    (params["shared"], shared_c, x), n_super - 1))
-        return out
-
     unit = _abs_slice(params["units"])
     cache_u = _abs_slice(abstract_cache["units"])
 
@@ -198,5 +161,5 @@ def decode_unit_programs(cfg: ModelConfig, abstract_params, abstract_cache,
                 new_c[f"b{j}"] = cb
         return xx, new_c
 
-    out.append(("unit", unit_fn, (unit, cache_u, x), cfg.n_units - 1))
+    out.append(("unit", unit_fn, (unit, cache_u, x), _scanned_extra(cfg)))
     return out

@@ -36,12 +36,13 @@ PALLAS_MIN_T = 512
 
 
 def attention_init(key, cfg: ModelConfig) -> Params:
-    d, h = cfg.d_model, cfg.head_dim
+    """q, k, v read `cfg.d_attn_in` features; o writes `d_model`."""
+    d, d_in, h = cfg.d_model, cfg.d_attn_in, cfg.head_dim
     k1, k2, k3, k4 = jax.random.split(key, 4)
     p = {
-        "wq": _dense_init(k1, (d, cfg.n_heads * h)),
-        "wk": _dense_init(k2, (d, cfg.n_kv_heads * h)),
-        "wv": _dense_init(k3, (d, cfg.n_kv_heads * h)),
+        "wq": _dense_init(k1, (d_in, cfg.n_heads * h)),
+        "wk": _dense_init(k2, (d_in, cfg.n_kv_heads * h)),
+        "wv": _dense_init(k3, (d_in, cfg.n_kv_heads * h)),
         "wo": _dense_init(k4, (cfg.n_heads * h, d)),
     }
     if cfg.qkv_bias:
@@ -136,7 +137,10 @@ def sdpa_chunked(q, k, v, q_pos, k_pos, window, softcap, scale,
     m0 = jnp.full((B, K, H // K, S), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, K, H // K, S), jnp.float32)
     a0 = jnp.zeros((B, K, H // K, S, D), jnp.float32)
-    (m, lsum, acc), _ = jax.lax.scan(body, (m0, l0, a0), (kc, vc, pc))
+    # the backward pass recomputes each chunk's scores from the carry: a
+    # plain scan would keep every chunk's, S x T in all
+    (m, lsum, acc), _ = jax.lax.scan(jax.checkpoint(body), (m0, l0, a0),
+                                     (kc, vc, pc))
     out = acc / jnp.maximum(lsum[..., None], 1e-37)
     return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, D).astype(q.dtype)
 
@@ -198,7 +202,7 @@ def attention(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                                     cfg.rope_theta, positions)
         q = apply_rope(q, cos, sin, cfg.rope_fraction)
         window = None
-    scale = cfg.head_dim ** -0.5
+    scale = cfg.attn_scale
     out = sdpa(q, k, v, positions, k_pos, window, cfg.attn_softcap, scale,
                impl, causal=causal)
     return jnp.einsum("bse,ed->bsd", out.reshape(B, S, -1), params["wo"])
@@ -253,7 +257,7 @@ def decode_attention(params: Params, x: jnp.ndarray, cache: Dict,
         # every encoder position is visible (no causal mask, no RoPE).
         ck, cv = cache["k"], cache["v"]
         k_pos = jnp.arange(L, dtype=jnp.int32)
-    scale = cfg.head_dim ** -0.5
+    scale = cfg.attn_scale
     with scope("sdpa"):
         out = sdpa_naive(q, ck, cv, pos[:, None], k_pos, window,
                          cfg.attn_softcap, scale, causal=not cross)

@@ -8,13 +8,14 @@ fp32), matching TPU mixed-precision practice.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from .scopes import scoped
+from .scopes import scope, scoped
 
 Params = Dict[str, jnp.ndarray]
 DTYPE = jnp.bfloat16
@@ -69,24 +70,45 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
 
 
 # --------------------------------------------------------------------------
-# MLP (SiLU-gated / GeGLU / plain GeLU)
+# MLP (SiLU-gated / GeGLU, tanh or exact erf / plain GeLU)
 # --------------------------------------------------------------------------
+
+GATED = {"silu": jax.nn.silu, "geglu": jax.nn.gelu,
+         "geglu_erf": functools.partial(jax.nn.gelu, approximate=False)}
+
 
 def mlp_init(key, d: int, d_ff: int, activation: str) -> Params:
     k1, k2, k3 = jax.random.split(key, 3)
     p = {"w_up": _dense_init(k1, (d, d_ff)),
          "w_down": _dense_init(k2, (d_ff, d))}
-    if activation in ("silu", "geglu"):
+    if activation in GATED:
         p["w_gate"] = _dense_init(k3, (d, d_ff))
     return p
 
 
+def adapter_init(key, d: int, d_ff: int, rank: int) -> Params:
+    """A rank-`rank` adapter on a gated MLP's gate and up projections."""
+    k1, k2, k3 = jax.random.split(key, 3)
+    return {"adapter_in": _dense_init(k1, (d, rank)),
+            "adapter_gate": _dense_init(k2, (rank, d_ff)),
+            "adapter_up": _dense_init(k3, (rank, d_ff))}
+
+
 @scoped("mlp")
-def mlp(params: Params, x: jnp.ndarray, activation: str) -> jnp.ndarray:
+def mlp(params: Params, x: jnp.ndarray, activation: str,
+        adapter: Params | None = None) -> jnp.ndarray:
+    """With `adapter`, gate and up each add (x @ adapter_in) @ adapter_*."""
     up = jnp.einsum("...d,df->...f", x, params["w_up"])
-    if activation in ("silu", "geglu"):
+    if activation in GATED:
         gate = jnp.einsum("...d,df->...f", x, params["w_gate"])
-        act = jax.nn.silu if activation == "silu" else jax.nn.gelu
+        if adapter is not None:
+            with scope("adapter"):
+                r = jnp.einsum("...d,dr->...r", x, adapter["adapter_in"])
+                gate = gate + jnp.einsum("...r,rf->...f", r,
+                                         adapter["adapter_gate"])
+                up = up + jnp.einsum("...r,rf->...f", r,
+                                     adapter["adapter_up"])
+        act = GATED[activation]
         h = act(gate.astype(jnp.float32)).astype(x.dtype) * up
     else:
         h = jax.nn.gelu(up.astype(jnp.float32)).astype(x.dtype)
@@ -108,7 +130,7 @@ def embedding_init(key, cfg: ModelConfig) -> Params:
 def embed(params: Params, tokens: jnp.ndarray,
           cfg: ModelConfig) -> jnp.ndarray:
     x = params["table"][tokens]
-    if cfg.tie_embeddings:
+    if cfg.tie_embeddings and cfg.scale_tied_embedding:
         # gemma-style embedding scaling keeps tied logits well-conditioned
         x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
     return x

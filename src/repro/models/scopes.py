@@ -8,7 +8,11 @@ compiled program's text maps the trace's ops to these scopes.
 
 `SCOPES` are disjoint: an op carries at most one of them.  `KERNELS` sit
 inside one of them and name the work that a Pallas kernel or its plain
-jnp counterpart does, whichever implementation runs.
+jnp counterpart does, whichever implementation runs.  `PARTS` sit inside
+one of them too and name a part of its work: `adapter`, a hybrid layer's
+own low-rank adapter inside the shared block's `mlp`.  `hybrid` holds
+what a hybrid layer adds around its shared block: the [x, x0] concat,
+its norm and the per-layer `linear`.
 """
 
 from __future__ import annotations
@@ -18,9 +22,10 @@ import re
 
 import jax
 
-SCOPES = ("embed", "norm", "attn", "mlp", "moe", "mamba", "head",
+SCOPES = ("embed", "norm", "attn", "mlp", "moe", "mamba", "hybrid", "head",
           "optimizer")
 KERNELS = ("sdpa", "ssd")
+PARTS = ("adapter",)
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=(.*)$", re.M)
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
@@ -31,8 +36,8 @@ _WRAPPED = re.compile(r"^[\w\-]+\((.*)\)$")
 
 
 def scope(name: str):
-    """The named scope `name`, one of SCOPES or KERNELS."""
-    if name not in SCOPES + KERNELS:
+    """The named scope `name`, one of SCOPES, KERNELS or PARTS."""
+    if name not in SCOPES + KERNELS + PARTS:
         raise ValueError(f"unknown scope {name!r}")
     return jax.named_scope(name)
 

@@ -8,7 +8,11 @@ Pallas kernel in repro.kernels/ssd tiles for VMEM; this module is the
 lowerable-everywhere jnp implementation (and the kernel's oracle lives in
 kernels/ssd/ref.py, mirroring this math).
 
-Single B/C group (n_groups=1), which matches the assigned configs.
+B and C come in `cfg.ssm_groups` groups: head h reads group h // (H/G), as
+Mamba2's reference code repeats each group over its heads, and the gated
+RMSNorm normalises each group's d_inner/G channels on their own.  One
+group (mamba2-130m) takes the single-group path; the Pallas kernel
+(kernels/ssd) takes one group only.
 """
 
 from __future__ import annotations
@@ -26,12 +30,13 @@ Params = Dict[str, jnp.ndarray]
 
 
 def mamba_init(key, cfg: ModelConfig) -> Params:
-    d, dssm, H, N = cfg.d_model, cfg.d_inner, cfg.n_ssm_heads, cfg.ssm_state
-    conv_dim = dssm + 2 * N
+    d, dssm, H = cfg.d_model, cfg.d_inner, cfg.n_ssm_heads
+    GN = cfg.ssm_groups * cfg.ssm_state
+    conv_dim = dssm + 2 * GN
     k1, k2, k3 = jax.random.split(key, 3)
     # in_proj emits [z, x, B, C, dt]
     return {
-        "in_proj": _dense_init(k1, (d, 2 * dssm + 2 * N + H)),
+        "in_proj": _dense_init(k1, (d, 2 * dssm + 2 * GN + H)),
         "conv_w": _dense_init(k2, (cfg.d_conv, conv_dim), 0),
         "conv_b": jnp.zeros((conv_dim,), jnp.bfloat16),
         "A_log": jnp.log(jnp.arange(1, H + 1, dtype=jnp.float32)),
@@ -43,9 +48,33 @@ def mamba_init(key, cfg: ModelConfig) -> Params:
 
 
 def _split_proj(cfg: ModelConfig, zxbcdt: jnp.ndarray):
-    dssm, N, H = cfg.d_inner, cfg.ssm_state, cfg.n_ssm_heads
-    z, xBC, dt = jnp.split(zxbcdt, [dssm, 2 * dssm + 2 * N], axis=-1)
+    dssm, GN = cfg.d_inner, cfg.ssm_groups * cfg.ssm_state
+    z, xBC, dt = jnp.split(zxbcdt, [dssm, 2 * dssm + 2 * GN], axis=-1)
     return z, xBC, dt
+
+
+def _split_xbc(cfg: ModelConfig, xBC: jnp.ndarray):
+    """[x, B, C] -> x, B, C; B and C as (..., N) for one group, else
+    (..., G, N)."""
+    dssm, G, N = cfg.d_inner, cfg.ssm_groups, cfg.ssm_state
+    xs, Bv, Cv = jnp.split(xBC, [dssm, dssm + G * N], axis=-1)
+    if G > 1:
+        Bv = Bv.reshape(Bv.shape[:-1] + (G, N))
+        Cv = Cv.reshape(Cv.shape[:-1] + (G, N))
+    return xs, Bv, Cv
+
+
+def _gated_norm(params: Params, y: jnp.ndarray, z: jnp.ndarray,
+                cfg: ModelConfig) -> jnp.ndarray:
+    """RMSNorm of y * silu(z), over each group's d_inner/G channels."""
+    g = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
+    G = cfg.ssm_groups
+    if G == 1:
+        return rmsnorm(params["gate_norm"], g, cfg.norm_eps)
+    scale = params["gate_norm"]["scale"]
+    gg = g.reshape(g.shape[:-1] + (G, -1))
+    out = rmsnorm({"scale": scale.reshape(G, -1)}, gg, cfg.norm_eps)
+    return out.reshape(g.shape)
 
 
 def _causal_conv(xBC: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray
@@ -73,8 +102,21 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
     """SSD chunked scan.
 
     x: (b, L, H, P); dt: (b, L, H) (post-softplus); A: (H,) negative;
-    B, C: (b, L, N) single group.  Returns (y (b,L,H,P), state (b,H,P,N)).
+    B, C: (b, L, N) single group, or (b, L, G, N), whose group g serves
+    heads g*H/G .. (g+1)*H/G - 1.  Returns (y (b,L,H,P), state (b,H,P,N)).
     """
+    if B.ndim == 4:                       # groups: one scan per group
+        b, L, H, P = x.shape
+        G = B.shape[2]
+        heads = (b, L, G, H // G)
+        st0 = (None if init_state is None
+               else init_state.reshape((b, G, H // G) + init_state.shape[2:]))
+        y, st = jax.vmap(
+            lambda x, dt, A, B, C, st0: ssd_scan(x, dt, A, B, C, chunk, st0),
+            in_axes=(2, 2, 0, 2, 2, None if st0 is None else 1),
+            out_axes=(2, 1))(x.reshape(heads + (P,)), dt.reshape(heads),
+                             A.reshape(G, H // G), B, C, st0)
+        return y.reshape(b, L, H, P), st.reshape((b, H) + st.shape[3:])
     b, L, H, P = x.shape
     N = B.shape[-1]
     Q = min(chunk, L)
@@ -130,17 +172,18 @@ def mamba_block(params: Params, x: jnp.ndarray, cfg: ModelConfig,
                 impl: str = "auto") -> jnp.ndarray:
     """Full-sequence Mamba2 block. x: (B, L, d) -> (B, L, d)."""
     B_, L, _ = x.shape
-    dssm, N, H, P = (cfg.d_inner, cfg.ssm_state, cfg.n_ssm_heads,
-                     cfg.ssm_head_dim)
+    dssm, H, P = cfg.d_inner, cfg.n_ssm_heads, cfg.ssm_head_dim
     z, xBC, dt = _split_proj(cfg, jnp.einsum("bld,de->ble", x,
                                              params["in_proj"]))
     xBC = _causal_conv(xBC, params["conv_w"], params["conv_b"])
-    xs, Bv, Cv = jnp.split(xBC, [dssm, dssm + N], axis=-1)
+    xs, Bv, Cv = _split_xbc(cfg, xBC)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
     A = -jnp.exp(params["A_log"])
     xh = xs.reshape(B_, L, H, P)
     with scope("ssd"):
         if impl == "pallas":
+            if cfg.ssm_groups > 1:
+                raise NotImplementedError("the SSD kernel takes one group")
             from repro.kernels.ssd.ops import ssd
             y, _ = ssd(xh, dt, A, Bv, Cv, chunk=cfg.ssm_chunk)
         else:
@@ -150,15 +193,14 @@ def mamba_block(params: Params, x: jnp.ndarray, cfg: ModelConfig,
             if pad:
                 xh = jnp.pad(xh, ((0, 0), (0, pad), (0, 0), (0, 0)))
                 dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-                Bv = jnp.pad(Bv, ((0, 0), (0, pad), (0, 0)))
-                Cv = jnp.pad(Cv, ((0, 0), (0, pad), (0, 0)))
+                grp = ((0, 0),) * (Bv.ndim - 2)
+                Bv = jnp.pad(Bv, ((0, 0), (0, pad)) + grp)
+                Cv = jnp.pad(Cv, ((0, 0), (0, pad)) + grp)
             y, _ = ssd_scan(xh, dt, A, Bv, Cv, Q)
             y = y[:, :L]
     y = y + params["D"].astype(y.dtype)[:, None] * xs.reshape(B_, L, H, P)
     y = y.reshape(B_, L, dssm)
-    y = rmsnorm(params["gate_norm"],
-                y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                cfg.norm_eps)
+    y = _gated_norm(params, y, z, cfg)
     return jnp.einsum("ble,ed->bld", y, params["out_proj"])
 
 
@@ -167,7 +209,7 @@ def mamba_block(params: Params, x: jnp.ndarray, cfg: ModelConfig,
 # --------------------------------------------------------------------------
 
 def init_ssm_cache(cfg: ModelConfig, batch: int) -> Dict[str, jnp.ndarray]:
-    conv_dim = cfg.d_inner + 2 * cfg.ssm_state
+    conv_dim = cfg.d_inner + 2 * cfg.ssm_groups * cfg.ssm_state
     return {
         "conv": jnp.zeros((batch, cfg.d_conv - 1, conv_dim), jnp.bfloat16),
         "state": jnp.zeros((batch, cfg.n_ssm_heads, cfg.ssm_head_dim,
@@ -184,8 +226,8 @@ def decode_mamba(params: Params, x: jnp.ndarray, cache: Dict,
     whatever the previous occupant of that row left in the cache."""
     B_ = x.shape[0]
     fresh = jnp.broadcast_to(jnp.asarray(pos) == 0, (B_,))
-    dssm, N, H, P = (cfg.d_inner, cfg.ssm_state, cfg.n_ssm_heads,
-                     cfg.ssm_head_dim)
+    dssm, G, N, H, P = (cfg.d_inner, cfg.ssm_groups, cfg.ssm_state,
+                        cfg.n_ssm_heads, cfg.ssm_head_dim)
     z, xBC, dt = _split_proj(cfg, jnp.einsum("bld,de->ble", x,
                                              params["in_proj"]))
     xBC = xBC[:, 0]
@@ -194,7 +236,10 @@ def decode_mamba(params: Params, x: jnp.ndarray, cache: Dict,
     conv = (window * params["conv_w"]).sum(axis=1) + params["conv_b"]
     xBC = jax.nn.silu(conv.astype(jnp.float32)).astype(x.dtype)
     new_conv = window[:, 1:]
-    xs, Bv, Cv = jnp.split(xBC, [dssm, dssm + N], axis=-1)
+    xs, Bv, Cv = jnp.split(xBC, [dssm, dssm + G * N], axis=-1)
+    # each head's group: (B, G*N) -> (B, H, N)
+    Bv = jnp.repeat(Bv.reshape(B_, G, N), H // G, axis=1)
+    Cv = jnp.repeat(Cv.reshape(B_, G, N), H // G, axis=1)
     dtv = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + params["dt_bias"])
     A = -jnp.exp(params["A_log"])
     dA = jnp.exp(dtv * A)                                    # (B, H)
@@ -202,14 +247,12 @@ def decode_mamba(params: Params, x: jnp.ndarray, cache: Dict,
     st = jnp.where(fresh[:, None, None, None], 0.0,
                    cache["state"].astype(jnp.float32))
     st = st * dA[:, :, None, None] + jnp.einsum(
-        "bh,bhp,bn->bhpn", dtv, xh.astype(jnp.float32),
+        "bh,bhp,bhn->bhpn", dtv, xh.astype(jnp.float32),
         Bv.astype(jnp.float32))
-    y = jnp.einsum("bhpn,bn->bhp", st, Cv.astype(jnp.float32))
+    y = jnp.einsum("bhpn,bhn->bhp", st, Cv.astype(jnp.float32))
     y = y + params["D"][:, None] * xh.astype(jnp.float32)
     y = y.reshape(B_, 1, dssm).astype(x.dtype)
-    y = rmsnorm(params["gate_norm"],
-                y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                cfg.norm_eps)
+    y = _gated_norm(params, y, z, cfg)
     out = jnp.einsum("ble,ed->bld", y, params["out_proj"])
     return out, {"conv": new_conv.astype(jnp.bfloat16),
                  "state": st.astype(jnp.bfloat16)}

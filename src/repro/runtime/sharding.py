@@ -100,6 +100,10 @@ def param_spec(mesh: Mesh, path: str, shape: Tuple[int, ...]) -> P:
         if len(shape) >= 3:
             return choose(["model", None], ["model", None], [fsdp, None])
         return choose(["model", None], [fsdp, None])
+    if last == "linear":              # hybrid layer's (d, d)
+        return choose([fsdp, None], ["model", None])
+    if last in ("adapter_gate", "adapter_up"):    # (rank, ff): TP on ff
+        return choose([None], ["model", None])
     if last == "router":              # (d, E)
         return choose([fsdp, None], [None])
     if last in ("in_proj", "out_proj"):   # mamba: TP on d_inner side
@@ -108,7 +112,7 @@ def param_spec(mesh: Mesh, path: str, shape: Tuple[int, ...]) -> P:
         return choose(["model", None], [fsdp, None])
     if last in ("conv_w", "conv_b"):
         return choose(*[[None]] * len(shape))
-    # norms, biases, scalars: replicated
+    # norms, biases, scalars and an adapter's (d, rank) adapter_in: replicated
     return P(*([None] * len(shape)))
 
 

@@ -79,16 +79,16 @@ def test_smoke_train_step(arch):
 # token while the forward pass runs a blocked scan, so the state drifts
 # by O(ulp) per step and the drift compounds over the sequence before
 # the vocab projection amplifies it.  For the zamba2 hybrid (a mamba
-# block per layer feeding a shared attention block) the observed error
-# grows roughly linearly in t up to ~0.42 at S=12; we bound it by
+# block per layer, two of them fed by shared attention blocks) the
+# observed error grows with t up to ~0.16 at S=12; we bound it by
 # S * n_layers * ulp = 12 * 4 * 2^-6 = 0.75 (one sign-flip of a 2-ulp
 # state perturbation per layer per step, at the [4,8) logit binade).
-_DECODE_TOL = {"zamba2-2.7b": 0.75}
+_DECODE_TOL = {"zamba2-7b": 0.75}
 
 
 @pytest.mark.parametrize("arch", ["smollm-360m", "gemma2-2b",
                                   "mixtral-8x22b", "mamba2-130m",
-                                  "zamba2-2.7b", "chatglm3-6b"])
+                                  "zamba2-7b", "chatglm3-6b"])
 def test_decode_matches_forward(arch):
     """Token-by-token decode must reproduce the full forward logits."""
     cfg = reduced(ARCHS[arch])

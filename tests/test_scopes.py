@@ -95,11 +95,21 @@ TINY = {
                 n_kv_heads=1, head_dim=16, d_ff=0, vocab_size=256,
                 ssm_state=16, d_conv=4, expand=2, ssm_head_dim=16,
                 ssm_chunk=16, tie_embeddings=True),
+    "hybrid": dict(name="t", family="hybrid", n_layers=4, d_model=64,
+                   n_heads=4, n_kv_heads=4, head_dim=32, d_ff=128,
+                   vocab_size=256, attn_input_dim=128, softmax_scale_dim=16,
+                   tie_embeddings=True, scale_tied_embedding=False,
+                   activation="geglu_erf", ssm_state=16, d_conv=4, expand=2,
+                   ssm_head_dim=16, ssm_chunk=16, ssm_groups=2,
+                   hybrid_layer_ids=(1, 3), num_mem_blocks=2,
+                   adapter_rank=8),
 }
 EXPECTED = {
     "dense": ({"embed", "norm", "attn", "mlp", "head", "optimizer"},
               {"sdpa"}),
     "ssm": ({"embed", "norm", "mamba", "head", "optimizer"}, {"ssd"}),
+    "hybrid": ({"embed", "norm", "attn", "mlp", "mamba", "hybrid", "head",
+                "optimizer"}, {"sdpa", "ssd"}),
 }
 
 
@@ -125,9 +135,17 @@ def _scopes_of(text):
 
 
 @pytest.mark.parametrize("family,impl", [
-    ("dense", "naive"), ("dense", "chunked"), ("ssm", "auto")])
+    ("dense", "naive"), ("dense", "chunked"), ("ssm", "auto"),
+    ("hybrid", "auto")])
 def test_compiled_train_step_carries_every_scope(family, impl):
     assert _scopes_of(_train_step_text(family, impl)) == EXPECTED[family]
+
+
+def test_adapter_is_a_part_of_the_shared_blocks_mlp():
+    names = scopes.program_ops(_train_step_text("hybrid"))["ops"].values()
+    inside = [n for n in names if scopes.in_scope(n, "adapter")]
+    assert inside
+    assert all(scopes.top_scope(n) == "mlp" for n in inside)
 
 
 def _instructions(text):
@@ -138,7 +156,7 @@ def _instructions(text):
                   if re.match(r"\s*(ROOT\s+)?%", line))
 
 
-@pytest.mark.parametrize("family", ["dense", "ssm"])
+@pytest.mark.parametrize("family", ["dense", "ssm", "hybrid"])
 def test_scopes_add_no_instruction(family, monkeypatch):
     with_scopes = _instructions(_train_step_text(family))
     monkeypatch.setattr(scopes.jax, "named_scope",
