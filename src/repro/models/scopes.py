@@ -8,11 +8,11 @@ compiled program's text maps the trace's ops to these scopes.
 
 `SCOPES` are disjoint: an op carries at most one of them.  `KERNELS` sit
 inside one of them and name the work that a Pallas kernel or its plain
-jnp counterpart does, whichever implementation runs.  `PARTS` sit inside
-one of them too and name a part of its work: `adapter`, a hybrid layer's
-own low-rank adapter inside the shared block's `mlp`.  `hybrid` holds
-what a hybrid layer adds around its shared block: the [x, x0] concat,
-its norm and the per-layer `linear`.
+jnp counterpart does, whichever implementation runs.  `PARTS` name a part
+of one: `adapter`, a hybrid layer's low-rank adapter in the shared `mlp`;
+`in_proj`, `mamba`'s input projection on a mesh that splits the SSM heads.
+`hybrid` holds what a hybrid layer adds around its shared block: the
+[x, x0] concat, its norm and the per-layer `linear`.
 """
 
 from __future__ import annotations
@@ -25,7 +25,7 @@ import jax
 SCOPES = ("embed", "norm", "attn", "mlp", "moe", "mamba", "hybrid", "head",
           "optimizer")
 KERNELS = ("sdpa", "ssd")
-PARTS = ("adapter",)
+PARTS = ("adapter", "in_proj")
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=(.*)$", re.M)
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')

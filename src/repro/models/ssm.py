@@ -13,20 +13,33 @@ Mamba2's reference code repeats each group over its heads, and the gated
 RMSNorm normalises each group's d_inner/G channels on their own.  One
 group (mamba2-130m) takes the single-group path; the Pallas kernel
 (kernels/ssd) takes one group only.
+
+On a mesh whose `model` axis cuts `in_proj`'s columns into n blocks, those
+blocks cut across [z | x | B | C | dt], so the block moves `in_proj`'s
+columns instead of its output: each chip gets its own heads' z, x and dt
+columns and every B and C column (`_mix_by_heads`), and the projection's
+outputs come out split by heads, B and C whole on every chip.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import ModelConfig
 from .layers import _dense_init, rmsnorm, rmsnorm_init
 from .scopes import scope, scoped
 
 Params = Dict[str, jnp.ndarray]
+
+# the name of in_proj's columns moved to each chip's heads
+# (`_mix_by_heads`): the one residual that transformer's remat keeps
+MOVED = "in_proj_by_heads"
 
 
 def mamba_init(key, cfg: ModelConfig) -> Params:
@@ -53,15 +66,116 @@ def _split_proj(cfg: ModelConfig, zxbcdt: jnp.ndarray):
     return z, xBC, dt
 
 
+def _by_group(cfg: ModelConfig, a: jnp.ndarray) -> jnp.ndarray:
+    """B or C (..., G*N) as (..., N) for one group, else (..., G, N)."""
+    G = cfg.ssm_groups
+    return a if G == 1 else a.reshape(a.shape[:-1] + (G, cfg.ssm_state))
+
+
 def _split_xbc(cfg: ModelConfig, xBC: jnp.ndarray):
-    """[x, B, C] -> x, B, C; B and C as (..., N) for one group, else
-    (..., G, N)."""
-    dssm, G, N = cfg.d_inner, cfg.ssm_groups, cfg.ssm_state
-    xs, Bv, Cv = jnp.split(xBC, [dssm, dssm + G * N], axis=-1)
-    if G > 1:
-        Bv = Bv.reshape(Bv.shape[:-1] + (G, N))
-        Cv = Cv.reshape(Cv.shape[:-1] + (G, N))
-    return xs, Bv, Cv
+    """[x, B, C] -> x, B, C; B and C by group (`_by_group`)."""
+    dssm, GN = cfg.d_inner, cfg.ssm_groups * cfg.ssm_state
+    xs, Bv, Cv = jnp.split(xBC, [dssm, dssm + GN], axis=-1)
+    return xs, _by_group(cfg, Bv), _by_group(cfg, Cv)
+
+
+def _model_axis() -> int:
+    """The size of the abstract mesh's `model` axis; 1 off a mesh."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return 1 if mesh.empty else dict(mesh.shape).get("model", 1)
+
+
+def _column_moves(cfg: ModelConfig, n: int):
+    """Where `in_proj`'s columns, stored in n blocks over `model`, go on the
+    head-aligned path.  Chip j needs its heads' columns [z_j | x_j | dt_j]
+    and every B and C column.  Returns {(src, dst): [(src column, count,
+    dst column)]}, the contiguous runs of chip src's block that make up
+    chip dst's [z_j | x_j | dt_j], and [(src, src column, count, column of
+    [B | C])], the runs that make up B and C."""
+    dssm, GN, H = cfg.d_inner, cfg.ssm_groups * cfg.ssm_state, cfg.n_ssm_heads
+    C = (2 * dssm + 2 * GN + H) // n
+
+    def runs(lo, hi):               # [lo, hi) of the fused columns by chip
+        for i in range(lo // C, (hi - 1) // C + 1):
+            a, b = max(lo, i * C), min(hi, (i + 1) * C)
+            yield i, a - i * C, b - a, a - lo
+
+    moves, at = {}, 0
+    for start, width in ((0, dssm // n), (dssm, dssm // n),
+                         (2 * dssm + 2 * GN, H // n)):
+        for dst in range(n):
+            lo = start + dst * width
+            for src, a, m, o in runs(lo, lo + width):
+                moves.setdefault((src, dst), []).append((a, m, at + o))
+        at += width
+    return moves, list(runs(2 * dssm, 2 * dssm + 2 * GN))
+
+
+def _mix_by_heads(params: Params, x: jnp.ndarray, cfg: ModelConfig,
+                  n: int):
+    """in_proj and the causal conv where in_proj's columns lie in n blocks
+    over `model`: z, x and dt split by heads over `model`, B and C whole on
+    every chip.  Each chip receives its heads' columns of in_proj, each run
+    from the chip that holds it, and every B and C column, summed over the
+    chips that hold them; then it multiplies and convolves its own
+    channels.  The gradient goes back the same way.  Both shard_maps take
+    every axis manual: XLA's CPU compiler cannot promote a bfloat16
+    all-reduce whose reducer carries the sharding of an automatic axis."""
+    from jax.sharding import PartitionSpec as P
+    moves, bc_runs = _column_moves(cfg, n)
+    dssm, H = cfg.d_inner, cfg.n_ssm_heads
+    zc, GN2 = dssm // n, 2 * cfg.ssm_groups * cfg.ssm_state
+
+    def place(cols, at, width):
+        return jnp.pad(cols, ((0, 0), (at, width - at - cols.shape[1])))
+
+    def move(w):
+        me = jax.lax.axis_index("model")
+        heads = []
+        for (src, dst), runs in moves.items():
+            cols = [w[:, a:a + m] for a, m, _ in runs]
+            if src == dst:
+                got = [jnp.where(me == src, c, 0) for c in cols]
+            else:
+                buf = jax.lax.ppermute(jnp.concatenate(cols, axis=1),
+                                       "model", [(src, dst)])
+                got = jnp.split(buf, np.cumsum([m for _, m, _ in runs])
+                                [:-1], axis=1)
+            heads += [place(c, o, 2 * zc + H // n)
+                      for (_, _, o), c in zip(runs, got)]
+        bc = jax.lax.psum(sum(
+            jnp.where(me == src, place(w[:, a:a + m], o, GN2), 0)
+            for src, a, m, o in bc_runs), "model")
+        return sum(heads), bc
+
+    def local(w, bc, x, conv_w, conv_b):
+        with scope("in_proj"):
+            z, xs, dt = jnp.split(jnp.einsum("bld,de->ble", x, w),
+                                  [zc, 2 * zc], axis=-1)
+            BC = jnp.einsum("bld,de->ble", x, bc)
+        mine = jax.lax.axis_index("model") * zc
+        xs = _causal_conv(xs,
+                          jax.lax.dynamic_slice_in_dim(conv_w, mine, zc, 1),
+                          jax.lax.dynamic_slice_in_dim(conv_b, mine, zc))
+        Bv, Cv = jnp.split(_causal_conv(BC, conv_w[:, dssm:], conv_b[dssm:]),
+                           2, axis=-1)
+        return z, xs, dt, _by_group(cfg, Bv), _by_group(cfg, Cv)
+
+    with scope("in_proj"):
+        w, bc = jax.shard_map(move, in_specs=P(None, "model"),
+                              out_specs=(P(None, "model"), P()))(
+            params["in_proj"])
+    # kept by transformer's remat, whose forward pass then moves no column
+    w, bc = checkpoint_name((w, bc), MOVED)
+    mesh = jax.sharding.get_abstract_mesh()
+    data = tuple(a for a in mesh.axis_names if a != "model")
+    rows = (data if data and x.shape[0] % math.prod(
+        mesh.shape[a] for a in data) == 0 else None)
+    split = P(rows, None, "model")
+    return jax.shard_map(
+        local, in_specs=(P(None, "model"), P(), P(rows), P(), P()),
+        out_specs=(split, split, split, P(rows), P(rows)))(
+        w, bc, x, params["conv_w"], params["conv_b"])
 
 
 def _gated_norm(params: Params, y: jnp.ndarray, z: jnp.ndarray,
@@ -173,10 +287,16 @@ def mamba_block(params: Params, x: jnp.ndarray, cfg: ModelConfig,
     """Full-sequence Mamba2 block. x: (B, L, d) -> (B, L, d)."""
     B_, L, _ = x.shape
     dssm, H, P = cfg.d_inner, cfg.n_ssm_heads, cfg.ssm_head_dim
-    z, xBC, dt = _split_proj(cfg, jnp.einsum("bld,de->ble", x,
-                                             params["in_proj"]))
-    xBC = _causal_conv(xBC, params["conv_w"], params["conv_b"])
-    xs, Bv, Cv = _split_xbc(cfg, xBC)
+    n = _model_axis()
+    # runtime/sharding.py splits in_proj's columns over `model` where n
+    # divides them; n dividing H keeps each chip's heads whole
+    if n > 1 and H % n == 0 and params["in_proj"].shape[-1] % n == 0:
+        z, xs, dt, Bv, Cv = _mix_by_heads(params, x, cfg, n)
+    else:
+        z, xBC, dt = _split_proj(cfg, jnp.einsum("bld,de->ble", x,
+                                                 params["in_proj"]))
+        xBC = _causal_conv(xBC, params["conv_w"], params["conv_b"])
+        xs, Bv, Cv = _split_xbc(cfg, xBC)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
     A = -jnp.exp(params["A_log"])
     xh = xs.reshape(B_, L, H, P)

@@ -33,7 +33,7 @@ from .layers import (_dense_init, adapter_init, embed, embedding_init, mlp,
                      mlp_init, rmsnorm, rmsnorm_init, unembed)
 from .moe import moe_block, moe_init
 from .scopes import scope
-from .ssm import decode_mamba, init_ssm_cache, mamba_block, mamba_init
+from .ssm import MOVED, decode_mamba, init_ssm_cache, mamba_block, mamba_init
 
 Params = Dict[str, Any]
 
@@ -187,8 +187,8 @@ def forward(params: Params, inputs: jnp.ndarray, cfg: ModelConfig,
     def remat_(fn):
         if not remat:
             return fn
-        return jax.checkpoint(fn,
-                              policy=jax.checkpoint_policies.nothing_saveable)
+        return jax.checkpoint(fn, policy=jax.checkpoint_policies
+                              .save_only_these_names(MOVED))
 
     body = remat_(unit_fn)
 

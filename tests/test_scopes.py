@@ -138,7 +138,11 @@ def _scopes_of(text):
     ("dense", "naive"), ("dense", "chunked"), ("ssm", "auto"),
     ("hybrid", "auto")])
 def test_compiled_train_step_carries_every_scope(family, impl):
-    assert _scopes_of(_train_step_text(family, impl)) == EXPECTED[family]
+    text = _train_step_text(family, impl)
+    assert _scopes_of(text) == EXPECTED[family]
+    # one device: no mesh splits the heads, so no `in_proj` part
+    assert not any(scopes.in_scope(n, "in_proj")
+                   for n in scopes.program_ops(text)["ops"].values())
 
 
 def test_adapter_is_a_part_of_the_shared_blocks_mlp():
@@ -146,6 +150,20 @@ def test_adapter_is_a_part_of_the_shared_blocks_mlp():
     inside = [n for n in names if scopes.in_scope(n, "adapter")]
     assert inside
     assert all(scopes.top_scope(n) == "mlp" for n in inside)
+
+
+def test_in_proj_is_a_part_of_mamba_taken_on_a_mesh_only():
+    """`in_proj` names the head-aligned input projection inside `mamba`:
+    present in the tiny hybrid's Mamba block on the (1, 4) mesh of four
+    CPU devices, absent from its one-device program.  The scalar adds of
+    an all-reduce's reduction body carry the body's own relative name,
+    outside `mamba`; no device op is one of them."""
+    import mesh_block
+    assert "in_proj" in scopes.PARTS
+    out = mesh_block.run(TINY["hybrid"])
+    inside = [n for n in out["ops_mesh"] if scopes.in_scope(n, "in_proj")]
+    assert {scopes.top_scope(n) for n in inside} - {None} == {"mamba"}
+    assert not any(scopes.in_scope(n, "in_proj") for n in out["ops_one"])
 
 
 def _instructions(text):

@@ -12,6 +12,8 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import contextlib
+import math
 import os
 import re
 
@@ -24,6 +26,7 @@ from repro.configs import ARCHS
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.rmsnorm.ops import rmsnorm
 from repro.kernels.ssd.ops import ssd
+from repro.models import scopes, ssm
 
 
 @pytest.fixture(scope="module")
@@ -181,3 +184,61 @@ def test_rmsnorm_compiles_d960(one_chip):
         lambda x, s: rmsnorm(x, s, interpret=False),
         _spec(one_chip, (8, 1024, d), jnp.bfloat16),
         _spec(one_chip, (d,), jnp.bfloat16))
+
+
+def _mamba_grad_text(cfg, mesh, x_sharding, param_sharding):
+    """The compiled gradient of one Mamba block at cfg's widths, 1 x 1024
+    tokens, its parameters placed by `param_sharding(name, shape)`."""
+    params = jax.eval_shape(lambda: ssm.mamba_init(jax.random.PRNGKey(0),
+                                                   cfg))
+    params = {k: jax.tree.map(lambda a, k=k: _spec(param_sharding(k, a.shape),
+                                                   a.shape, a.dtype), v)
+              for k, v in params.items()}
+    x = _spec(x_sharding, (1, 1024, cfg.d_model), jnp.bfloat16)
+
+    def loss(p, x):
+        return ssm.mamba_block(p, x, cfg).astype(jnp.float32).sum()
+
+    with jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext():
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            params, x).compile().as_text()
+
+
+COLLECTIVE = re.compile(r"= (\S+|\(.*?\)) (all-to-all|all-gather|all-reduce|"
+                        r"collective-permute|reduce-scatter)(?:-start)?\(")
+
+
+def test_mamba_block_moves_in_proj_columns_on_a_mesh(v5e):
+    """zamba2-7b's Mamba block, forward and backward, on the (1, 4) mesh
+    that splits in_proj's 14704 columns in blocks of 3676 across its
+    parts: no collective carries in_proj's output (a shape with 14704 or
+    3676 columns), and no all-gather is as large as a whole part of the
+    weight, bf16[3584, 7168].  The columns move to each chip's heads."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_auto_mesh
+    from repro.runtime.sharding import param_spec
+    cfg = ARCHS["zamba2-7b"]
+    mesh = make_auto_mesh((1, 4), ("data", "model"), devices=v5e)
+    text = _mamba_grad_text(
+        cfg, mesh, NamedSharding(mesh, P("data")),
+        lambda name, shape: NamedSharding(mesh, param_spec(mesh, name,
+                                                           shape)))
+    found = COLLECTIVE.findall(text)
+    assert any(op == "collective-permute" for _, op in found)
+    for shape, op in found:
+        dims = [int(d) for d in re.findall(r"\d+", " ".join(
+            re.findall(r"\[([\d,]*)\]", shape)))]
+        assert not {14704, 3676} & set(dims), (op, shape)
+        if op == "all-gather":
+            assert math.prod(dims) < 3584 * 7168, shape
+    assert any(scopes.in_scope(n, "in_proj")
+               for n in scopes.program_ops(text)["ops"].values())
+
+
+def test_mamba_block_on_one_chip_keeps_the_fused_projection(one_chip):
+    """mamba2-130m's Mamba block on one chip: no mesh cuts in_proj, so the
+    block multiplies by it whole and the `in_proj` scope never appears."""
+    text = _mamba_grad_text(ARCHS["mamba2-130m"], None, one_chip,
+                            lambda name, shape: one_chip)
+    assert not any(scopes.in_scope(n, "in_proj")
+                   for n in scopes.program_ops(text)["ops"].values())

@@ -17,13 +17,14 @@ import pytest
 
 from repro.configs import ARCHS, reduced
 from repro.configs.base import ModelConfig
-from repro.models import build_model, param_count, transformer
+from repro.models import build_model, param_count, scopes, transformer
 from repro.models.ssm import ssd_scan
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "chipbench")
 sys.path[:0] = [BENCH, os.path.join(BENCH, "tests")]
 
+import mesh_block  # noqa: E402
 from reference import ssm as ref_ssm  # noqa: E402
 from reference import zamba2 as ref  # noqa: E402
 from reference.common import quantizer, to_f32  # noqa: E402
@@ -214,6 +215,29 @@ def test_flops_per_step_of_the_cell():
     per_token = 12 * mamba + 2 * hybrid + 2 * 32000 * d
     got = train_step_flops(_config(), 4, 4096)
     assert got == 3 * 4 * 4096 * per_token == 180_736_116_129_792
+
+
+def test_mamba_block_on_four_devices_matches_one_device():
+    """On the (1, 4) mesh `model` cuts a tiny Zamba2's in_proj (64 x 328:
+    z 128 | x 128 | B 32 | C 32 | dt 8) into four blocks of 82 columns
+    that cut across its parts.  The block then moves in_proj's columns to
+    each device's heads (the `in_proj` scope, which the one-device program
+    lacks) and computes in float32 what one device computes: the output
+    and the gradients of in_proj, conv_w, conv_b and the input agree to
+    1e-5 of their norms, the order of the sums aside."""
+    out = mesh_block.run(TINY)
+    for name in ("y", "in_proj", "conv_w", "conv_b", "x"):
+        assert out[name] < 1e-5, (name, out[name])
+    assert any(scopes.in_scope(n, "in_proj") for n in out["ops_mesh"])
+    assert not any(scopes.in_scope(n, "in_proj") for n in out["ops_one"])
+
+
+def test_remat_keeps_the_moved_columns():
+    """The tiny Zamba2's gradient on the (1, 4) mesh moves in_proj's columns
+    as often under the train step's remat as without it: the remat keeps
+    the moved columns (`ssm.MOVED`), so its forward pass moves none."""
+    n = mesh_block.run(TINY)["moves"]
+    assert n["remat"] == n["plain"] > 0, n
 
 
 def test_every_large_matrix_is_split_over_four_chips():
