@@ -1,5 +1,5 @@
-"""Benchmark driver: one function per paper table/figure + the LM-scale
-reports.  Prints ``name,us_per_call,derived`` CSV rows and writes the full
+"""Benchmark driver: one function per paper table/figure.  Prints
+``name,us_per_call,derived`` CSV rows and writes the full
 structured results to experiments/bench_results.json (keys sorted, and
 ``--only <row>`` merges into the existing file — so adding or refreshing
 one row churns only that row's diff).  Each run also persists a
@@ -55,9 +55,6 @@ META_KEY = "_bench_meta"
 #:   balancer_vs_sweep         default  integer win counts
 #:   mapping_sensitivity       default  closed-form ratio
 #:   edp_report                default  closed-form energy-delay
-#:   roofline_table_*          default  integer cell counts
-#:   hybrid_plane_report       default  dryrun-derived, deterministic
-#:   dryrun_summary            default  integer ok counts
 #:   fig_resilience            1e-6     deterministic scenario grid
 #:                                      (explicit entry: retained
 #:                                      ratios divide two engine
@@ -211,12 +208,12 @@ def check_rows(rows, committed: dict, rtol: float | None = None) -> int:
 
 
 def main(argv=None) -> int:
-    from benchmarks import lm_scale, paper_figs
+    from benchmarks import paper_figs
     from repro.core import make_trace
     from repro.core.workloads import WORKLOADS
 
     # traces are built on first use: --only rows that never read them
-    # (hetero_codesign, roofline/dryrun) and the unknown-row error path
+    # (hetero_codesign, scaling_frontier) and the unknown-row error path
     # skip the 15-workload build entirely
     _traces = {}
 
@@ -320,23 +317,6 @@ def main(argv=None) -> int:
              r["_summary"]["reshard_never_slower"],
              r["_summary"]["resharded_cells"],
              r["_summary"]["n_cells"])),
-        ("roofline_table_baseline",
-         lm_scale.roofline_table,
-         lambda r: "cells=%d" % len(r)),
-        ("roofline_table_optimized",
-         lambda: lm_scale.roofline_table(
-             "pod", lm_scale.DRYRUN_DIR + "_opt"),
-         lambda r: "cells=%d" % len(r)),
-        ("hybrid_plane_report",
-         lambda: lm_scale.hybrid_plane_report(
-             "pod", lm_scale.DRYRUN_DIR + "_opt"),
-         lambda r: "cells=%d;max_coll_speedup=%.2f;mean_step_speedup=%.3f"
-         % (len(r), max((x["balancer_coll_speedup"] for x in r),
-                        default=1.0),
-            (sum(x["balancer_step_speedup"] for x in r) / max(1, len(r))))),
-        ("dryrun_summary",
-         lm_scale.dryrun_summary,
-         lambda r: "ok=%d/%d" % (r["ok"], r["total"])),
     ]
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--only", action="append", metavar="ROW",

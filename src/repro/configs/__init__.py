@@ -1,6 +1,6 @@
 """Architecture registry: every assigned arch + the paper's platform."""
 
-from .base import ModelConfig, ShapeConfig, SHAPES, BlockSpec
+from .base import ModelConfig, BlockSpec
 from .zamba2_7b import CONFIG as zamba2_7b
 from .chatglm3_6b import CONFIG as chatglm3_6b
 from .gemma2_2b import CONFIG as gemma2_2b
@@ -24,10 +24,6 @@ ARCHS = {
     "pixtral-12b": pixtral_12b,
     "seamless-m4t-large-v2": seamless_m4t_v2,
 }
-
-
-def get_arch(name: str) -> ModelConfig:
-    return ARCHS[name]
 
 
 def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
@@ -66,17 +62,3 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
     small.update(overrides)
     return dataclasses.replace(cfg, **small)
 
-
-def cells(arch: str):
-    """The (arch x shape) cells assigned to this arch (skips documented in
-    DESIGN.md SArch-applicability: long_500k only for sub-quadratic archs)."""
-    cfg = get_arch(arch)
-    out = []
-    for shape in SHAPES.values():
-        if shape.name == "long_500k" and not cfg.subquadratic:
-            continue
-        out.append(shape)
-    return out
-
-
-ALL_CELLS = [(a, s.name) for a in ARCHS for s in cells(a)]

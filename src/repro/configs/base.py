@@ -1,10 +1,9 @@
 """Config system: architecture + run configuration.
 
 Every assigned architecture is a `ModelConfig` in its own module under
-`repro.configs`, selectable by ``--arch <id>`` everywhere (launcher,
-dry-run, benchmarks).  A config fully determines the model: the repeating
-pattern unit (the `lax.scan` body), attention flavour, MoE/SSM settings,
-and the modality frontend stub.
+`repro.configs`, selectable by ``--arch <id>`` in the launchers.  A config
+fully determines the model: the repeating pattern unit (the `lax.scan`
+body), attention flavour, MoE/SSM settings, and the modality frontend stub.
 """
 
 from __future__ import annotations
@@ -84,13 +83,11 @@ class ModelConfig:
     n_encoder_layers: int = 0
 
     # modality frontend stub: "none" => token ids in; "embed" => the
-    # dry-run feeds precomputed frame/patch embeddings (B, S, d_model)
+    # model is fed precomputed frame/patch embeddings (B, S, d_model)
     frontend: str = "none"
     encoder_frontend: str = "none"
 
     norm_eps: float = 1e-6
-    # whether this arch can run the 524k-token long-context decode shape
-    subquadratic: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -216,20 +213,3 @@ class ModelConfig:
         return self.param_count() - n_moe_layers * (per_moe_total -
                                                     per_moe_active)
 
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    """One assigned input-shape cell."""
-
-    name: str
-    seq_len: int
-    global_batch: int
-    mode: str           # "train" | "prefill" | "decode"
-
-
-SHAPES = {
-    "train_4k": ShapeConfig("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524288, 1, "decode"),
-}

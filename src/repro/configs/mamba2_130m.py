@@ -15,5 +15,4 @@ CONFIG = ModelConfig(
     vocab_size=50280,
     ssm_state=128,
     tie_embeddings=True,
-    subquadratic=True,
 )

@@ -16,5 +16,4 @@ CONFIG = ModelConfig(
     n_experts=8,
     experts_per_token=2,
     sliding_window=4096,
-    subquadratic=True,    # SWA: KV cache capped at the window
 )

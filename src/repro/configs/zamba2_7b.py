@@ -32,5 +32,4 @@ CONFIG = ModelConfig(
     num_mem_blocks=2,
     adapter_rank=128,
     norm_eps=1e-5,
-    subquadratic=True,          # SSM backbone; only the shared blocks keep KV
 )

@@ -1,11 +1,11 @@
 """The paper's technique at LM scale: hybrid plane scheduling for the
 collectives of the compiled training/serving step.
 
-TPU mapping (DESIGN.md S3): the wired plane is the ICI torus; the second,
+TPU mapping: the wired plane is the ICI torus; the second,
 broadcast-natured plane is a shared-medium overlay (in-package wireless on
 future parts, or the DCN/host network today) with single-hop semantics.
-The dry-run gives us, per (arch x shape x mesh) cell, the exact per-op
-collective payload bytes of the compiled XLA program; this module
+Given a compiled XLA program's per-op collective payload bytes (a dict
+op -> bytes per device per step), this module
 
 1. classifies each collective as *multicast-shaped* (all-gather,
    all-to-all's broadcast half, collective-permute fan-outs) or
@@ -32,9 +32,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Tuple
 
-from repro.launch.roofline import V5E
-
 OVERLAY_BW = 100e9          # B/s shared broadcast plane, per pod
+# Google Cloud documentation, "TPU v5e": 1,600 Gbit/s of interchip
+# interconnect a chip, 4 links of 50 GB/s
+ICI_LINKS = 4
+ICI_BW = 50e9               # B/s per link
 MULTICAST_OPS = ("all-gather", "all-gather-start", "all-to-all",
                  "collective-permute", "collective-permute-start")
 REDUCTION_OPS = ("all-reduce", "all-reduce-start", "reduce-scatter")
@@ -87,7 +89,7 @@ def eligible_volume(flows: List[CollectiveFlow],
 
 def wired_time(flows: List[CollectiveFlow], offloaded: float = 0.0) -> float:
     total = sum(f.wired_link_bytes for f in flows)
-    return max(0.0, total - offloaded) / (V5E.ici_links * V5E.ici_bw)
+    return max(0.0, total - offloaded) / (ICI_LINKS * ICI_BW)
 
 
 def overlay_time(volume: float, pcfg: PlaneConfig) -> float:
@@ -150,7 +152,7 @@ def balance_cell(coll_per_op: Dict[str, float], t_compute: float,
     flows = flows_from_coll_per_op(coll_per_op, pcfg.ring_radius)
     L = sum(f.wired_link_bytes for f in flows)
     elig = eligible_volume(flows, pcfg)
-    b_ici = V5E.ici_links * V5E.ici_bw
+    b_ici = ICI_LINKS * ICI_BW
     v_star = L * overlay_bw / (b_ici + overlay_bw)
     v = min(v_star, elig)
     t_wired = wired_time(flows)

@@ -1,7 +1,7 @@
 """Mesh construction and the persistent compile cache.
 
 FUNCTIONS (not module-level constants) so importing this module never
-touches jax device state; the dry-run sets XLA_FLAGS before first init.
+touches jax device state.
 """
 
 from __future__ import annotations
@@ -63,15 +63,6 @@ def make_auto_mesh(shape, axes, devices=None):
     """`jax.make_mesh` with every axis Auto (GSPMD decides the collectives)."""
     return jax.make_mesh(shape, axes, devices=devices,
                          axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """Single pod: (data=16, model=16) = 256 chips (one v5e pod).
-    Multi-pod: (pod=2, data=16, model=16) = 512 chips; the 'pod' axis
-    carries cross-pod data parallelism over DCN."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh():

@@ -1,4 +1,5 @@
-"""Serving launcher: batched decode with continuous batching.
+"""Serving launcher: batched decode with continuous batching, on the
+host's mesh (every device on the `model` axis).
 
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
         --requests 16 --slots 4 --max-new 16 [--full]
@@ -21,8 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, reduced
-from repro.launch.mesh import (make_host_mesh, make_production_mesh,
-                               use_compile_cache)
+from repro.launch.mesh import make_host_mesh, use_compile_cache
 from repro.models import build_model
 from repro.obs.metrics import get_logger
 from repro.runtime.parallel import ParallelContext, parallel_context
@@ -38,8 +38,6 @@ def parse_args(argv=None):
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--max-len", type=int, default=96)
-    ap.add_argument("--mesh", default="host",
-                    choices=["host", "pod", "multipod"])
     ap.add_argument("--reduced", action="store_true", default=True)
     ap.add_argument("--full", dest="reduced", action="store_false")
     return ap.parse_args(argv)
@@ -54,8 +52,7 @@ def run(argv=None) -> dict:
     cfg = ARCHS[args.arch]
     if args.reduced:
         cfg = reduced(cfg, vocab_size=min(cfg.vocab_size, 4096))
-    mesh = (make_host_mesh() if args.mesh == "host"
-            else make_production_mesh(multi_pod=args.mesh == "multipod"))
+    mesh = make_host_mesh()
     scfg = ServeConfig(max_len=args.max_len)
 
     rng = np.random.default_rng(0)

@@ -2,7 +2,7 @@
 
     PYTHONPATH=src python -m repro.launch.train --arch smollm-360m \
         --steps 200 --batch 16 --seq 128 [--full] [--resume] \
-        [--mesh host|one|pod|multipod] [--compress] [--microbatches 4] \
+        [--mesh host|one] [--compress] [--microbatches 4] \
         [--profile-dir DIR --profile-steps 2:4]
 
 Wires together everything the framework provides: mesh + sharding rules,
@@ -37,8 +37,7 @@ from repro.checkpoint.checkpointer import (AsyncCheckpointer, latest_steps,
 from repro.configs import ARCHS, reduced
 from repro.data.pipeline import DataConfig, batch_for_model
 from repro.launch.mesh import (compile_work, make_host_mesh,
-                               make_one_device_mesh, make_production_mesh,
-                               use_compile_cache)
+                               make_one_device_mesh, use_compile_cache)
 from repro.models.scopes import program_ops
 from repro.obs.metrics import get_logger
 from repro.units import MEGA
@@ -57,8 +56,6 @@ MAX_RESTARTS = 3          # failed steps restored before the run gives up
 MESHES = {
     "host": make_host_mesh,
     "one": make_one_device_mesh,
-    "pod": make_production_mesh,
-    "multipod": lambda: make_production_mesh(multi_pod=True),
 }
 
 

@@ -118,10 +118,8 @@ PRINT_ALLOWED_SUFFIXES = ("obs/metrics.py", "lint/cli.py")
 #: ``__post_init__``.
 UNVALIDATED_CONFIGS = {
     # jax model-plane configs: shapes are validated by jax.eval_shape
-    # at init time; numeric fields have no domain beyond "positive",
-    # and the dryrun harness exercises every zoo entry.
+    # at init time; numeric fields have no domain beyond "positive".
     "BlockSpec": "model-plane; shape-checked by jax at init",
-    "ShapeConfig": "derived serving shapes; checked by make_serve_fns",
     "ServeConfig": "serving knobs; exercised by launch/serve drivers",
     "TrainConfig": "training knobs; exercised by launch/train drivers",
     "DataConfig": "pipeline knobs; any seed/int is valid",

@@ -1,8 +1,8 @@
 """Encoder-decoder model (SeamlessM4T backbone).
 
 Encoder: bidirectional self-attention + MLP over precomputed frame
-embeddings (the speech frontend is a stub per the assignment — the
-dry-run's `input_specs()` supplies (B, S_src, d) embeddings).
+embeddings (the speech frontend is a stub: the batch's `src_embeds`
+supplies (B, S_src, d) embeddings).
 Decoder: causal self-attention + cross-attention + MLP, standard KV-cache
 decode with the cross K/V computed once from the encoder output.
 """

@@ -7,8 +7,8 @@
     logits, cache = model.decode(params, cache, token, pos)
 
 `batch` is a dict: {"tokens"} or {"embeds"} (frontend stubs), plus
-{"src_embeds"} for enc-dec.  This is the surface the runtime, launcher and
-dry-run all program against.
+{"src_embeds"} for enc-dec.  This is the surface the runtime and the
+launchers program against.
 """
 
 from __future__ import annotations

@@ -2,8 +2,8 @@
 
 The repeating pattern unit (cfg.unit) is the `lax.scan` body; parameters
 are stacked (n_units, ...) so a 61-layer MoE lowers as one unit body + a
-scan — critical for CPU-host compile times in the 512-device dry-run and
-the standard TPU practice anyway.
+scan — which keeps compile times flat in depth, the standard TPU
+practice.
 
 Hybrid (zamba2) models stack a Mamba2 block per layer as `units`, and
 before each layer of `cfg.hybrid_layer_ids` the k-th such layer applies

@@ -1,8 +1,8 @@
 """Serving: batched prefill + single-token decode steps.
 
-`serve_step` is what the decode_* dry-run shapes lower: one new token per
-sequence against a KV cache of the cell's seq_len.  A tiny continuous-
-batching scheduler drives it in launch/serve.py.
+`decode_step` decodes one new token per sequence against a KV cache of
+max_len positions.  A tiny continuous-batching scheduler drives it in
+launch/serve.py.
 """
 
 from __future__ import annotations
@@ -29,7 +29,7 @@ def make_serve_fns(cfg: ModelConfig, scfg: ServeConfig):
 
     def prefill(params, batch) -> Tuple[jnp.ndarray, Any]:
         """Full-sequence forward; returns last-position logits + nothing
-        cache-ful (the dry-run decode cells build the cache abstractly)."""
+        cache-ful: decode builds its cache with `init_cache`."""
         logits, _ = model.apply(params, batch)
         return logits[:, -1]
 

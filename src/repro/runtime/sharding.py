@@ -8,15 +8,14 @@ Strategy (megatron-style TP x FSDP x DP, on mesh axes
 - a dimension is only assigned to a mesh axis when the axis size divides
   it — otherwise the rule falls back down a preference list and finally to
   replication (GSPMD would pad uneven shardings, but staying divisible
-  keeps collective volumes exact and the roofline honest);
+  keeps collective volumes exact);
 - activations: batch on ("pod","data"); long-context (batch=1) shapes
   shard the sequence axis instead (context parallelism);
 - KV caches: batch on ("pod","data"), kv-heads on 'model' when divisible,
   else sequence on 'model'.
 
-These rules actuate the wireless-paper analogue at LM scale: WHERE a
-tensor is cut decides which collectives (multicast-shaped all-gathers vs
-reduction traffic) the compiled step emits — see core/hybrid_schedule.py.
+Where a tensor is cut decides which collectives (multicast-shaped
+all-gathers vs reduction traffic) the compiled step emits.
 """
 
 from __future__ import annotations
@@ -84,7 +83,7 @@ def param_spec(mesh: Mesh, path: str, shape: Tuple[int, ...]) -> P:
     if last in ("table",):            # (V, d): vocab-parallel embedding.
         # d stays replicated: sharding d on the batch ('data') axis makes
         # the unembed contraction compete with batch sharding and GSPMD
-        # replicates the full-batch logits (EXPERIMENTS.md SPerf H-gemma).
+        # replicates the full-batch logits.
         return choose(["model", None], [None])
     if last == "unembed":             # (d, V)
         return choose([None], ["model", None])

@@ -1,7 +1,6 @@
 """Training step: CE loss, remat, gradient accumulation, optional gradient
 compression, optimizer update.  All control flow is jax.lax; the whole
-step jits to one XLA program whose collectives the hybrid-plane scheduler
-(core/hybrid_schedule.py) consumes.
+step jits to one XLA program.
 """
 
 from __future__ import annotations
@@ -28,27 +27,20 @@ class TrainConfig:
     compression: Optional[CompressionConfig] = None
     attention_impl: str = "auto"
     remat: bool = True
-    loss_impl: str = "onehot"        # "onehot" (shard-local) | "gather"
 
 
 def cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray,
-                  z_loss_weight: float = 0.0,
-                  impl: str = "onehot") -> Tuple[jnp.ndarray, jnp.ndarray]:
+                  z_loss_weight: float = 0.0
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Mean CE over tokens (+z-loss). logits fp32 (B,S,V), labels (B,S).
 
-    impl="gather" (take_along_axis) makes GSPMD all-gather vocab-sharded
-    logits; impl="onehot" expresses the label pick as an iota-compare
-    masked reduction, which stays shard-local (+ a scalar psum).  The
-    before/after is logged in EXPERIMENTS.md SPerf (hillclimb H1)."""
+    The label pick is an iota-compare masked reduction, which stays local
+    to each shard of vocab-sharded logits (+ a scalar psum);
+    take_along_axis would make GSPMD all-gather them."""
     lse = jax.nn.logsumexp(logits, axis=-1)
-    if impl == "gather":
-        ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    else:
-        V = logits.shape[-1]
-        hit = (jax.lax.broadcasted_iota(jnp.int32, logits.shape,
-                                        logits.ndim - 1)
-               == labels[..., None])
-        ll = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+    hit = (jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+           == labels[..., None])
+    ll = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
     ce = (lse - ll).mean()
     zl = (lse ** 2).mean()
     return ce + z_loss_weight * zl, ce
@@ -61,7 +53,7 @@ def make_loss_fn(cfg: ModelConfig, tcfg: TrainConfig):
         logits, aux = model.apply(params, batch)
         with scope("head"):
             loss, ce = cross_entropy(logits, batch["labels"],
-                                     tcfg.z_loss_weight, tcfg.loss_impl)
+                                     tcfg.z_loss_weight)
         total = loss + tcfg.aux_loss_weight * aux
         return total, {"ce": ce, "aux": aux}
 

@@ -18,26 +18,9 @@ from repro.models.attention import PALLAS_MIN_T
 from repro.optim.optimizers import OptimizerConfig
 from repro.runtime.train import TrainConfig, make_train_step
 
+from arch_batch import arch_batch
+
 ARCH_NAMES = list(ARCHS)
-
-
-def _batch(cfg, B=2, S=32, key=0):
-    rng = np.random.default_rng(key)
-    batch = {}
-    if cfg.is_encdec:
-        batch["src_embeds"] = jnp.asarray(
-            rng.standard_normal((B, 16, cfg.d_model)), jnp.bfloat16)
-        batch["tokens"] = jnp.asarray(
-            rng.integers(0, cfg.vocab_size, (B, S)), jnp.int32)
-    elif cfg.frontend == "embed":
-        batch["embeds"] = jnp.asarray(
-            rng.standard_normal((B, S, cfg.d_model)), jnp.bfloat16)
-    else:
-        batch["tokens"] = jnp.asarray(
-            rng.integers(0, cfg.vocab_size, (B, S)), jnp.int32)
-    batch["labels"] = jnp.asarray(
-        rng.integers(0, cfg.vocab_size, (B, S)), jnp.int32)
-    return batch
 
 
 @pytest.mark.parametrize("arch", ARCH_NAMES)
@@ -45,7 +28,7 @@ def test_smoke_forward(arch):
     cfg = reduced(ARCHS[arch])
     model = build_model(cfg, remat=False)
     params = model.init(jax.random.PRNGKey(0))
-    batch = _batch(cfg)
+    batch = arch_batch(cfg)
     logits, aux = model.apply(params, batch)
     S = 32
     assert logits.shape == (2, S, cfg.vocab_size)
@@ -61,7 +44,7 @@ def test_smoke_train_step(arch):
                        remat=False)
     step_fn, init_fn = make_train_step(cfg, tcfg)
     state = init_fn(jax.random.PRNGKey(0))
-    state2, metrics = jax.jit(step_fn)(state, _batch(cfg))
+    state2, metrics = jax.jit(step_fn)(state, arch_batch(cfg))
     assert bool(jnp.isfinite(metrics["loss"]))
     assert int(state2["step"]) == 1
     # parameters actually moved
